@@ -186,6 +186,8 @@ def run(smoke: bool = False, json_out: bool = False) -> bool:
 
 
 def main() -> int:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="minimal workload for CI: gates reported but "

@@ -207,17 +207,11 @@ def crash_smoke() -> bool:
     """`--crash-smoke`: SIGKILL a child engine mid-run, restore from its
     last committed snapshot, drain, and assert every request's
     `resumed + tokens` stream is bit-identical to a never-crashed
-    in-process oracle."""
-    model = get_model(ARCH, smoke=True)
-    params = model.init_params(jax.random.PRNGKey(0))
-    prompts = _prompts(CRASH_BATCH, model.cfg.vocab)
-    oracle_engine = ServingEngine(model, params=params,
-                                  max_batch=CRASH_BATCH,
-                                  prefill_chunk=PROMPT_LEN)
-    oracle_handles = _crash_submit(oracle_engine, prompts)
-    oracle_engine.run()
-    oracle = {h.rid: list(h.tokens) for h in oracle_handles}
+    in-process oracle.
 
+    The child runs to its SIGKILL before this process touches JAX: a
+    device belongs to one process, so a parent holding it would starve
+    the child."""
     with tempfile.TemporaryDirectory() as d:
         proc = subprocess.run(
             [sys.executable, "-m", "benchmarks.bench_serving",
@@ -226,6 +220,15 @@ def crash_smoke() -> bool:
             print(f"crash child exited rc={proc.returncode}, "
                   f"expected {-signal.SIGKILL}", flush=True)
             return False
+        model = get_model(ARCH, smoke=True)
+        params = model.init_params(jax.random.PRNGKey(0))
+        oracle_engine = ServingEngine(model, params=params,
+                                      max_batch=CRASH_BATCH,
+                                      prefill_chunk=PROMPT_LEN)
+        oracle_handles = _crash_submit(
+            oracle_engine, _prompts(CRASH_BATCH, model.cfg.vocab))
+        oracle_engine.run()
+        oracle = {h.rid: list(h.tokens) for h in oracle_handles}
         t0 = time.perf_counter()
         engine = ServingEngine.restore(d, params=params)
         handles = engine.handles          # run() pops them as lanes finish
@@ -278,6 +281,8 @@ def run(*, smoke: bool = False, devices: int | None = None,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="one batch size (CI-sized)")

@@ -285,6 +285,8 @@ def run(*, smoke: bool = False, json_path: str | None = None,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized traces (16 unloaded / 48 overload)")

@@ -218,6 +218,8 @@ def run(smoke: bool = False, json_out: bool = False) -> bool:
 
 
 def main() -> int:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="minimal sweep for CI: K in {2,4}, few ticks; "

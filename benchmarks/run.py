@@ -20,6 +20,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_energy_proxy, bench_kernels,
                             bench_prefill, bench_prefix_cache,
                             bench_quant_ablation, bench_resources,

@@ -82,7 +82,8 @@ def main():
         return
     ok = True
     for h, p in zip(handles, prompts):
-        ref = sequential_decode(model, params, p, args.tokens)
+        ref = sequential_decode(model, params, p, args.tokens,
+                                width=args.max_batch)
         match = streamed[h.rid] == ref == h.tokens
         ok &= match
         print(f"req{h.rid}: engine == sequential decode: {match}")

@@ -32,7 +32,7 @@ _LOG2E_HW = 1.0 + 0.25 + 0.125 + 0.0625  # 1.0111_2 = 1.4375
 # 256-entry fraction LUT: 2^(i/256) rounded to 8 fractional bits (paper:
 # "eight-bit precision"), stored once as a module constant.
 EXP_LUT_TABLE = np.round(np.exp2(np.arange(256) / 256.0) * 256.0) / 256.0
-_EXP_LUT = jnp.asarray(EXP_LUT_TABLE, jnp.float32)
+_EXP_LUT = EXP_LUT_TABLE.astype(np.float32)   # numpy: no backend at import
 
 
 def exp_lut(x: jnp.ndarray, *, table: jnp.ndarray | None = None
@@ -52,7 +52,7 @@ def exp_lut(x: jnp.ndarray, *, table: jnp.ndarray | None = None
     u = jnp.floor(y)
     v = y - u
     idx = jnp.clip((v * 256.0).astype(jnp.int32), 0, 255)
-    frac = (_EXP_LUT if table is None else table)[idx]
+    frac = jnp.asarray(_EXP_LUT if table is None else table)[idx]
     return jnp.exp2(u) * frac
 
 
@@ -113,7 +113,7 @@ def _build_div_lut() -> np.ndarray:
 
 
 DIV_LUT_TABLE = _build_div_lut()
-_DIV_LUT = jnp.asarray(DIV_LUT_TABLE.reshape(-1), jnp.float32)
+_DIV_LUT = DIV_LUT_TABLE.reshape(-1).astype(np.float32)
 
 
 def div_lut(x: jnp.ndarray, y: jnp.ndarray, *,
@@ -138,7 +138,7 @@ def div_lut(x: jnp.ndarray, y: jnp.ndarray, *,
     my, ey = my * 2.0, ey - 1
     ix = jnp.clip(((mx - 1.0) * 16.0).astype(jnp.int32), 0, 15)
     iy = jnp.clip(((my - 1.0) * 16.0).astype(jnp.int32), 0, 15)
-    frac = (_DIV_LUT if table is None else table)[ix * 16 + iy]
+    frac = jnp.asarray(_DIV_LUT if table is None else table)[ix * 16 + iy]
     q = frac * jnp.exp2((ex - ey).astype(jnp.float32))
     q = jnp.where(ay <= 0, jnp.float32(2.0**15), q)  # saturate on div-by-0
     q = jnp.where(ax <= 0, 0.0, q)

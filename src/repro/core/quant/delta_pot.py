@@ -244,17 +244,14 @@ def dpot_decode_codes(codes: jnp.ndarray, ks: Sequence[int]) -> jnp.ndarray:
     c = codes.astype(jnp.int32)
     total = jnp.zeros(codes.shape, jnp.float32)
     q_cum = jnp.zeros(codes.shape, jnp.float32)
-    alive = jnp.ones(codes.shape, dtype=bool)
+    alive = None    # no all-true constant mask: Mosaic cannot relayout it
     for k in ks:
         dq = c & ((1 << k) - 1)
         c = c >> k
-        alive = alive & (dq > 0)
+        alive = (dq > 0) if alive is None else alive & (dq > 0)
         q_cum = q_cum + dq.astype(jnp.float32)
         term = jnp.where(alive, jnp.exp2(-q_cum), 0.0)
         total = total + term
-        # freeze q_cum growth once dead (harmless either way since term is 0,
-        # but keeps exponents small)
-        q_cum = jnp.where(alive, q_cum, q_cum)
     return total
 
 

@@ -116,6 +116,12 @@ def pack_params(params, policy: PlanePolicy | None = None):
     return jax.tree_util.tree_unflatten(tdef, out)
 
 
+def _sign(bit):
+    """±1.0 from a sign bit, as arithmetic: a select between two constants
+    needs a replicated mask layout that Mosaic cannot produce."""
+    return 1.0 - 2.0 * bit.astype(jnp.float32)
+
+
 def unpack_leaf(leaf):
     """Decode one quantized plane leaf -> bf16 weights (identity on
     anything else).  The single source of truth for the decode numerics:
@@ -132,18 +138,18 @@ def unpack_leaf(leaf):
         return vq_dequantize(leaf["vq_idx"],
                              leaf["codebook"]).astype(jnp.bfloat16)
     if plane == "w4":
-        p = leaf["packed4"]
+        p = leaf["packed4"].astype(jnp.int32)
         lo = p & 0xF
         hi = (p >> 4) & 0xF
         words = jnp.stack([lo, hi], axis=-2).reshape(
             p.shape[:-2] + (2 * p.shape[-2], p.shape[-1]))
-        codes = (words & 0x7).astype(jnp.uint8)
-        sign = jnp.where((words >> 3) & 1, -1.0, 1.0)
+        codes = words & 0x7
+        sign = _sign((words >> 3) & 1)
         lvl = dpot_decode_codes(codes, FORMAT_W4.ks)
         return (sign * lvl * leaf["scale"]).astype(jnp.bfloat16)
-    p = leaf["packed"]
-    codes = (p & 0x7F).astype(jnp.uint8)
-    sign = jnp.where((p >> 7) & 1, -1.0, 1.0)
+    p = leaf["packed"].astype(jnp.int32)   # Mosaic has no u8 -> f32 cast
+    codes = p & 0x7F
+    sign = _sign((p >> 7) & 1)
     lvl = dpot_decode_codes(codes, FORMAT_W8.ks)
     return (sign * lvl * leaf["scale"]).astype(jnp.bfloat16)
 
@@ -204,36 +210,45 @@ def unpack_params(packed):
 
 
 # ---------------------------------------------------------------------------
-# Fused layer stack: per-layer weights as ONE contiguous chunk per layer
+# Fused layer stack: per-layer weights as contiguous slab blocks
 # ---------------------------------------------------------------------------
 #
 # The paper's weight stream (§4.2) is chunked: the accelerator fetches each
 # layer's weights as one contiguous block and double-buffers the next
 # layer's chunk behind the current layer's compute.  `fuse_layer_stack`
 # realizes that layout on the host — every stacked (L, ...) leaf of a
-# block tree is flattened into a per-dtype (L, N) slab (uint8 Δ-PoT code
-# planes and bf16 weights each get their own slab), while broadcast
-# leading-1 leaves (shared packed scales, LUT tables) stay separate as
-# resident operands.  The whole-model decode megakernel
+# block tree is stacked row-wise into an (L, R, W) slab shared by all
+# leaves of one dtype and one last-axis width W (uint8 Δ-PoT code planes
+# and bf16 weights each get their own slabs; a vector leaf is one row),
+# while broadcast leading-1 leaves (shared packed scales, LUT tables) stay
+# separate as resident operands.  Each leaf starts on a whole TPU tile row
+# (8 rows at 32 bits, 16 at 16, 32 at 8) and spans the slab's full width,
+# so a layer's slab block tiles exactly and each leaf is a STATIC,
+# tile-aligned row slice.  The whole-model decode megakernel
 # (`kernels.fused_decode.fused_model_decode`) then fetches layer l as one
-# slab row per dtype and re-materializes the per-layer tree with STATIC
-# slices inside the kernel (`unfuse_layer`) — stacked packed-leaf slicing
-# inside the kernel, one memory stream per layer instead of one gather per
-# leaf.  Packing reshapes and concatenates only, so the decoded weights
-# are bit-identical to the unfused tree.
+# slab block per (dtype, width) and re-materializes the per-layer tree
+# with those static slices (`unfuse_layer`) — one memory stream per slab
+# per layer instead of one gather per leaf.  Packing reshapes, pads and
+# concatenates only, so the decoded weights are bit-identical to the
+# unfused tree.
+
+
+def _tile_rows(dtype) -> int:
+    """Rows of one TPU tile for `dtype`: 8 at 32 bits, 16 at 16, 32 at 8."""
+    return 32 // min(4, jnp.dtype(dtype).itemsize)
 
 
 @jax.tree_util.register_pytree_node_class
 class FusedLayerStack:
     """A stacked per-layer parameter tree in chunked-stream form.
 
-    slabs    — {dtype name: (L, N) array}: layer l's weights of that dtype,
-               contiguous.
+    slabs    — {"<dtype>:<width>": (L, R, W) array}: layer l's weights of
+               that dtype and last-axis width, stacked row-wise.
     aux      — tuple of broadcast leading-1 leaves kept out of the slabs
                (shared Δ-PoT scales, LUT tables): VMEM-resident operands.
     manifest — static per-leaf recipe aligned with the original tree's
-               flatten order: ("slab", dtype, offset, per-layer shape) or
-               ("aux", index).
+               flatten order: ("slab", key, row offset, per-layer shape)
+               or ("aux", index).
     tdef     — the original tree's treedef (packed {"packed","scale"}
                dicts reassemble automatically).
     """
@@ -261,24 +276,35 @@ class FusedLayerStack:
         return cls(slabs, aux, manifest, tdef)
 
 
-def fuse_layer_stack(blocks, n_layers: int) -> FusedLayerStack:
-    """Pack a stacked per-layer block tree into per-dtype (L, N) slabs.
+def _leaf_rows(shape) -> tuple[int, int]:
+    """(rows, width) of one per-layer leaf laid out in a slab."""
+    width = shape[-1] if shape else 1
+    rows = int(np.prod(shape[:-1], dtype=np.int64)) if len(shape) > 1 else 1
+    return rows, width
 
-    Values are only reshaped/concatenated, never converted — unfusing is
-    bit-identical.  Do this ONCE outside the decode step (the serving
-    engine and `Model.prepare_fused_model_params` do): repacking inside a
-    jitted step would copy every weight per token."""
+
+def fuse_layer_stack(blocks, n_layers: int) -> FusedLayerStack:
+    """Pack a stacked per-layer block tree into (L, R, W) slabs, one per
+    (dtype, last-axis width).
+
+    Values are only reshaped/padded/concatenated, never converted —
+    unfusing is bit-identical.  Do this ONCE outside the decode step (the
+    serving engine and `Model.prepare_fused_model_params` do): repacking
+    inside a jitted step would copy every weight per token."""
     flat, tdef = jax.tree_util.tree_flatten(blocks)
     manifest, aux, parts, offs = [], [], {}, {}
     for leaf in flat:
         if leaf.ndim and leaf.shape[0] == n_layers:
-            key = jnp.dtype(leaf.dtype).name
             shape = tuple(leaf.shape[1:])
-            n = int(np.prod(shape, dtype=np.int64)) if shape else 1
+            rows, width = _leaf_rows(shape)
+            key = f"{jnp.dtype(leaf.dtype).name}:{width}"
             manifest.append(("slab", key, offs.get(key, 0), shape))
+            padded = -(-rows // _tile_rows(leaf.dtype)) * \
+                _tile_rows(leaf.dtype)
+            part = jnp.reshape(leaf, (n_layers, rows, width))
             parts.setdefault(key, []).append(
-                jnp.reshape(leaf, (n_layers, n)))
-            offs[key] = offs.get(key, 0) + n
+                jnp.pad(part, ((0, 0), (0, padded - rows), (0, 0))))
+            offs[key] = offs.get(key, 0) + padded
         elif leaf.ndim and leaf.shape[0] == 1:
             manifest.append(("aux", len(aux)))
             aux.append(leaf)
@@ -293,18 +319,20 @@ def fuse_layer_stack(blocks, n_layers: int) -> FusedLayerStack:
 
 
 def unfuse_layer(rows, aux_vals, manifest, tdef):
-    """Rebuild ONE layer's parameter tree from its slab rows.
+    """Rebuild ONE layer's parameter tree from its slab blocks.
 
-    rows     — {dtype name: (N,) slab row for layer l} (or abstract).
+    rows     — {slab key: (R, W) block for layer l} — an array, or any
+               object whose `[a:b]` gives rows a..b (the megakernel
+               passes ref views, so only the rows a leaf needs are read).
     aux_vals — broadcast leaves with the leading 1 squeezed.
-    All slices are STATIC (offsets come from the manifest), so inside a
-    kernel this compiles to views feeding the consumers — the only
-    per-layer memory stream is the slab row fetch itself."""
+    All slices are STATIC and tile-aligned (offsets come from the
+    manifest), so inside a kernel this compiles to views feeding the
+    consumers — the only per-layer memory stream is the slab fetch."""
     leaves = []
     for entry in manifest:
         if entry[0] == "slab":
             _, key, off, shape = entry
-            n = int(np.prod(shape, dtype=np.int64)) if shape else 1
+            n, _ = _leaf_rows(shape)
             leaves.append(rows[key][off:off + n].reshape(shape))
         else:
             leaves.append(aux_vals[entry[1]])

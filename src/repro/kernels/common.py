@@ -2,10 +2,22 @@
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+class CompileError(RuntimeError):
+    """A program failed to trace, lower or compile.  That is a fault of the
+    code or of its shapes, never of the device: serving re-raises it
+    instead of retrying or demoting the path (serving.scheduler)."""
+
+
+def f32_dot(x, w):
+    """`x @ w` accumulated in f32 and rounded once to the operands' result
+    dtype — the rounding XLA gives a bf16 `x @ w`, in the only form a
+    compiled Pallas TPU kernel lowers (Mosaic takes 32-bit accumulators
+    only)."""
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(
+        jnp.result_type(x, w))
 
 
 def exact_jit(fn, donate_argnums=()):
@@ -35,16 +47,27 @@ def exact_jit(fn, donate_argnums=()):
                     else leaf
                     for leaf in jax.tree_util.tree_leaves(args))
         if key not in cache:
-            cache[key] = jitted.lower(*args).compile(
-                compiler_options={"xla_allow_excess_precision": False})
+            try:
+                cache[key] = jitted.lower(*args).compile(
+                    compiler_options={"xla_allow_excess_precision": False})
+            except Exception as e:
+                raise CompileError(
+                    f"{getattr(fn, '__name__', fn)!s} failed to compile: "
+                    f"{e}") from e
         return cache[key](*args)
     return call
 
 
 def interpret_default(interpret: bool | None) -> bool:
-    """Pallas kernels target TPU; everywhere else (this CPU container)
-    they run in interpret mode, which executes the kernel body in Python —
-    the correctness-validation path required by the assignment."""
+    """Pallas kernels compile for the TPU; on the CPU backend (tests,
+    `JAX_PLATFORMS=cpu`) they run in interpret mode, which executes the
+    kernel body as XLA ops — the correctness-validation path.  Any other
+    backend is an error: interpreting there would hide the device."""
     if interpret is None:
-        return not on_tpu()
+        backend = jax.default_backend()
+        if backend not in ("cpu", "tpu"):
+            raise RuntimeError(
+                f"Pallas kernels run compiled on 'tpu' or interpreted on "
+                f"'cpu'; the default backend is {backend!r}")
+        return backend == "cpu"
     return interpret

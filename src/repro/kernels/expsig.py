@@ -23,7 +23,7 @@ from jax.experimental import pallas as pl
 from repro.core.approx.units import EXP_LUT_TABLE, _LOG2E_HW
 from repro.kernels.common import interpret_default
 
-_LUT = jnp.asarray(EXP_LUT_TABLE, jnp.float32)
+_LUT = EXP_LUT_TABLE.astype(np.float32)   # numpy: no backend at import
 
 
 def _kernel(x_ref, lut_ref, o_ref, *, mode: int):

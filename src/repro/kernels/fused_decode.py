@@ -179,6 +179,44 @@ def _state_tile_spec(shape, bb: int):
                         lambda i, _nd=nd: (0, i) + (0,) * (_nd - 2))
 
 
+class _SlabRows:
+    """Rows `[a:b]` of one layer of an (L, R, W) slab ref, read on demand:
+    `unfuse_layer` slices it per leaf, so a kernel loads each leaf's own
+    tile-aligned rows instead of the whole layer block at once."""
+
+    def __init__(self, ref, layer):
+        self.ref, self.layer = ref, layer
+
+    def __getitem__(self, rows: slice):
+        return self.ref[self.layer, rows, :]
+
+
+# v5e has 128 MiB of VMEM per core; the default scoped limit (16 MiB) is
+# far below one layer's double-buffered weight blocks at published widths
+_VMEM_CAP = 100 * 2**20
+
+
+def megakernel_vmem_bytes(stack: FusedLayerStack, weights: str = "stream"
+                          ) -> int:
+    """Scoped VMEM to request for the compiled megakernel over `stack`.
+
+    The grid pipeline holds two layer blocks per slab when streaming (all
+    L when resident); the in-kernel weight decode needs room beside them,
+    so the weight blocks may take at most half of the cap.  Raises
+    ValueError when they cannot, so a width that cannot stream is refused
+    when the plan is built, not when the program compiles."""
+    nbytes = [a.size * a.dtype.itemsize for a in stack.slabs.values()]
+    held = sum(nbytes) * 2 // stack.n_layers if weights == "stream" \
+        else sum(nbytes)
+    if held > _VMEM_CAP // 2:
+        raise ValueError(
+            f"the {weights} megakernel would hold {held / 2**20:.1f} MiB of "
+            f"weight blocks in VMEM, more than half of its "
+            f"{_VMEM_CAP // 2**20} MiB; this width needs a K-tiled weight "
+            "stream (serve it with fused_decode=False or 'block')")
+    return _VMEM_CAP
+
+
 def fused_model_decode(block_fn, x, blocks, state, *, bb: int | None = None,
                        weights: str | None = None,
                        interpret: bool | None = None):
@@ -303,9 +341,9 @@ def fused_model_decode(block_fn, x, blocks, state, *, bb: int | None = None,
         [jax.ShapeDtypeStruct((n_layers, B) + tuple(a.shape[1:]), a.dtype)
          for a in new_st_leaves_ab])
 
-    def layer_params(rows, aux_vals):
-        return unfuse_layer(dict(zip(slab_keys, rows)), aux_vals,
-                            manifest, bl_tdef)
+    def layer_params(slab_refs, layer, aux_vals):
+        rows = {k: _SlabRows(r, layer) for k, r in zip(slab_keys, slab_refs)}
+        return unfuse_layer(rows, aux_vals, manifest, bl_tdef)
 
     if weights == "stream":
         # -- grid over (batch tile, layer); residual carried in scratch --
@@ -320,7 +358,7 @@ def fused_model_decode(block_fn, x, blocks, state, *, bb: int | None = None,
                 x_scr[...] = in_refs[0][...].astype(x_scr.dtype)
 
             lp = layer_params(
-                [r[...][0] for r in in_refs[1:1 + n_sl]],
+                in_refs[1:1 + n_sl], 0,
                 [r[...][0] for r in in_refs[1 + n_sl:1 + n_bl]])
             st = jax.tree_util.tree_unflatten(
                 st_tdef, [r[...][0] for r in in_refs[1 + n_bl:]])
@@ -359,7 +397,7 @@ def fused_model_decode(block_fn, x, blocks, state, *, bb: int | None = None,
 
             def body(l, xx):
                 lp = layer_params(
-                    [r[l] for r in in_refs[1:1 + n_sl]],
+                    in_refs[1:1 + n_sl], l,
                     [r[0] for r in in_refs[1 + n_sl:1 + n_bl]])
                 st = jax.tree_util.tree_unflatten(
                     st_tdef, [r[l] for r in in_refs[1 + n_bl:]])
@@ -391,6 +429,8 @@ def fused_model_decode(block_fn, x, blocks, state, *, bb: int | None = None,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            vmem_limit_bytes=megakernel_vmem_bytes(blocks, weights)),
         interpret=interpret,   # resolved above (weights default needs it)
     )(x, *slab_leaves, *aux_leaves, *st_leaves)
     x2 = outs[0]

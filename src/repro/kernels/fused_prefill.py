@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.quant.serving import is_packed_leaf, leaf_plane, unpack_leaf
-from repro.kernels.common import interpret_default
+from repro.kernels.common import f32_dot, interpret_default
 
 
 def _mm_kernel(x_ref, wq_ref, scale_ref, o_ref, *, dt):
@@ -52,7 +52,7 @@ def _mm_kernel(x_ref, wq_ref, scale_ref, o_ref, *, dt):
     # compute dtype) so the fused prefill is bit-identical, not merely close
     w = unpack_leaf({"packed": wq_ref[...],
                      "scale": scale_ref[...]}).astype(dt)
-    o_ref[...] = x_ref[...] @ w
+    o_ref[...] = f32_dot(x_ref[...], w)
 
 
 def _mm_kernel_w4(x_ref, wq_ref, scale_ref, o_ref, *, dt):
@@ -61,7 +61,7 @@ def _mm_kernel_w4(x_ref, wq_ref, scale_ref, o_ref, *, dt):
     # HBM code bytes of the W8 kernel above, identical bits out
     w = unpack_leaf({"packed4": wq_ref[...],
                      "scale": scale_ref[...]}).astype(dt)
-    o_ref[...] = x_ref[...] @ w
+    o_ref[...] = f32_dot(x_ref[...], w)
 
 
 def _mm_kernel_vq(x_ref, idx_ref, cb_ref, o_ref, *, dt):
@@ -70,7 +70,7 @@ def _mm_kernel_vq(x_ref, idx_ref, cb_ref, o_ref, *, dt):
     # Δ-PoT scales) — the gather decode never touches HBM-decoded weights
     w = unpack_leaf({"vq_idx": idx_ref[...],
                      "codebook": cb_ref[...]}).astype(dt)
-    o_ref[...] = x_ref[...] @ w
+    o_ref[...] = f32_dot(x_ref[...], w)
 
 
 def _fit(block: int, dim: int) -> int:
@@ -78,6 +78,17 @@ def _fit(block: int, dim: int) -> int:
     while dim % block != 0:
         block //= 2
     return block
+
+
+def _fit_lanes(block: int, dim: int) -> tuple[int, int]:
+    """(block, grid extent) along a lane (last) axis.  A dim that 128 does
+    not divide (the 50,277-wide vocab head) keeps a 128-multiple block and
+    a ragged last block — halving to a divisor would end at unaligned
+    width-1 tiles — whose out-of-range columns are never written back."""
+    if dim <= block or dim % 128 == 0:
+        block = _fit(block, dim)
+        return block, dim // block
+    return block, pl.cdiv(dim, block)
 
 
 @functools.partial(jax.jit, static_argnames=("dt", "bm", "bn", "interpret"))
@@ -97,11 +108,11 @@ def dpot_chunk_matmul(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,
     K2, N = wq.shape
     assert K == K2, (x.shape, wq.shape)
     scale = scale.reshape(1, N)
-    bm, bn = _fit(bm, M), _fit(bn, N)
+    bm, (bn, gn) = _fit(bm, M), _fit_lanes(bn, N)
     out_dt = jnp.result_type(x.dtype, jnp.dtype(dt))
     return pl.pallas_call(
         functools.partial(_mm_kernel, dt=jnp.dtype(dt)),
-        grid=(M // bm, N // bn),
+        grid=(M // bm, gn),
         in_specs=[
             pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
             pl.BlockSpec((K, bn), lambda i, j: (0, j)),
@@ -126,11 +137,11 @@ def w4_chunk_matmul(x: jnp.ndarray, wq4: jnp.ndarray, scale: jnp.ndarray,
     Kh, N = wq4.shape
     assert K == 2 * Kh, (x.shape, wq4.shape)
     scale = scale.reshape(1, N)
-    bm, bn = _fit(bm, M), _fit(bn, N)
+    bm, (bn, gn) = _fit(bm, M), _fit_lanes(bn, N)
     out_dt = jnp.result_type(x.dtype, jnp.dtype(dt))
     return pl.pallas_call(
         functools.partial(_mm_kernel_w4, dt=jnp.dtype(dt)),
-        grid=(M // bm, N // bn),
+        grid=(M // bm, gn),
         in_specs=[
             pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
             pl.BlockSpec((Kh, bn), lambda i, j: (0, j)),
@@ -156,11 +167,11 @@ def vq_chunk_matmul(x: jnp.ndarray, idx: jnp.ndarray, codebook: jnp.ndarray,
     assert K == K2, (x.shape, idx.shape)
     cb = codebook.reshape(-1)
     C = cb.shape[0]
-    bm, bn = _fit(bm, M), _fit(bn, N)
+    bm, (bn, gn) = _fit(bm, M), _fit_lanes(bn, N)
     out_dt = jnp.result_type(x.dtype, jnp.dtype(dt))
     return pl.pallas_call(
         functools.partial(_mm_kernel_vq, dt=jnp.dtype(dt)),
-        grid=(M // bm, N // bn),
+        grid=(M // bm, gn),
         in_specs=[
             pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
             pl.BlockSpec((K, bn), lambda i, j: (0, j)),

@@ -42,6 +42,9 @@ from repro.kernels.common import interpret_default
 
 def _kernel(k_ref, v_ref, w_ref, u_ref, a0_ref, b0_ref, o0_ref, *refs,
             T: int, masked: bool, carry: str | None, luts: bool):
+    # every operand is at least 2-D with a unit second-minor axis, so each
+    # (1, bc) row is a whole TPU tile row; the math is elementwise per
+    # channel, so the row shapes leave the bits of the recurrence unchanged
     refs = list(refs)
     valid_ref = refs.pop(0) if masked else None
     if luts:
@@ -53,47 +56,39 @@ def _kernel(k_ref, v_ref, w_ref, u_ref, a0_ref, b0_ref, o0_ref, *refs,
         exp_fn = jnp.exp
         div_fn = lambda x, y: x / y
     y_ref, af_ref, bf_ref, of_ref = refs
-    w = w_ref[...].astype(jnp.float32)
+    w = w_ref[...].astype(jnp.float32)                     # (1, bc)
     u = u_ref[...].astype(jnp.float32)
     snap = ((lambda x: x) if carry is None else
             (lambda x: x.astype(jnp.dtype(carry)).astype(jnp.float32)))
 
     def body(t, state):
         a, b, o = state
-        tsl = (pl.dslice(0, 1), pl.dslice(t, 1), slice(None))
-        kt = pl.load(k_ref, tsl)[0, 0]
-        vt = pl.load(v_ref, tsl)[0, 0]
-        kt = kt.astype(jnp.float32)
-        vt = vt.astype(jnp.float32)
+        kt = k_ref[0, pl.ds(t, 1), :].astype(jnp.float32)  # (1, bc)
+        vt = v_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
         # output (includes the bonus u for the current token)
         no = jnp.maximum(o, u + kt)
         A = exp_fn(o - no)
         Bf = exp_fn(u + kt - no)
         y = div_fn(A * a + Bf * vt, A * b + Bf)
-        pl.store(y_ref, tsl, y[None, None].astype(y_ref.dtype))
+        y_ref[0, pl.ds(t, 1), :] = y.astype(y_ref.dtype)
         # state update
         no2 = jnp.maximum(o - w, kt)
         A2 = exp_fn(o - w - no2)
         B2 = exp_fn(kt - no2)
         na, nb, no_ = A2 * a + B2 * vt, A2 * b + B2, no2
         if masked:
-            ok = pl.load(valid_ref,
-                         (pl.dslice(0, 1), pl.dslice(t, 1)))[0, 0] != 0
+            ok = valid_ref[0, pl.ds(t, 1), :] != 0        # (1, 1)
             na = jnp.where(ok, na, a)
             nb = jnp.where(ok, nb, b)
             no_ = jnp.where(ok, no_, o)
         return (snap(na), snap(nb), snap(no_))
 
-    # int ref indices break jax 0.4.x interpret-mode discharge; use dslice
-    ld = lambda ref: pl.load(
-        ref, (pl.dslice(0, 1), slice(None)))[0].astype(jnp.float32)
+    ld = lambda ref: ref[0].astype(jnp.float32)           # (1, bc)
     a, b, o = jax.lax.fori_loop(
         0, T, body, (ld(a0_ref), ld(b0_ref), ld(o0_ref)))
-    st = lambda ref, x: pl.store(
-        ref, (pl.dslice(0, 1), slice(None)), x[None])
-    st(af_ref, a)
-    st(bf_ref, b)
-    st(of_ref, o)
+    af_ref[0] = a
+    bf_ref[0] = b
+    of_ref[0] = o
 
 
 @functools.partial(jax.jit,
@@ -121,31 +116,34 @@ def wkv4_pallas(k: jnp.ndarray, v: jnp.ndarray, w: jnp.ndarray,
     if (exp_table is None) != (div_table is None):
         raise ValueError("exp_table and div_table travel together")
     grid = (B, C // bc)
+    # TPU tiling: a block's last two axes must be whole tiles or whole
+    # axes, so the per-row operands carry a unit axis — state (B, 1, C),
+    # the per-channel vectors (1, C) and the commit mask (B, T, 1)
     seq_spec = pl.BlockSpec((1, T, bc), lambda b, c: (b, 0, c))
-    vec_spec = pl.BlockSpec((bc,), lambda b, c: (c,))
-    st_spec = pl.BlockSpec((1, bc), lambda b, c: (b, c))
-    operands = [k, v, w, u, a0, b0, o0]
+    vec_spec = pl.BlockSpec((1, bc), lambda b, c: (0, c))
+    st_spec = pl.BlockSpec((1, 1, bc), lambda b, c: (b, 0, c))
+    row = lambda s: s.reshape(B, 1, C)
+    operands = [k, v, w.reshape(1, C), u.reshape(1, C),
+                row(a0), row(b0), row(o0)]
     in_specs = [seq_spec, seq_spec, vec_spec, vec_spec,
                 st_spec, st_spec, st_spec]
     if valid is not None:
-        operands.append(valid.astype(jnp.int32))
-        in_specs.append(pl.BlockSpec((1, T), lambda b, c: (b, 0)))
+        operands.append(valid.astype(jnp.int32).reshape(B, T, 1))
+        in_specs.append(pl.BlockSpec((1, T, 1), lambda b, c: (b, 0, 0)))
     if exp_table is not None:
         tab_spec = pl.BlockSpec((256,), lambda b, c: (0,))
         operands += [exp_table, div_table]
         in_specs += [tab_spec, tab_spec]
+    st_shape = jax.ShapeDtypeStruct((B, 1, C), jnp.float32)
     y, af, bf, of = pl.pallas_call(
         functools.partial(_kernel, T=T, masked=valid is not None,
                           carry=carry_dtype, luts=exp_table is not None),
         grid=grid,
         in_specs=in_specs,
         out_specs=[seq_spec, st_spec, st_spec, st_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, C), jnp.float32),
-            jax.ShapeDtypeStruct((B, C), jnp.float32),
-            jax.ShapeDtypeStruct((B, C), jnp.float32),
-            jax.ShapeDtypeStruct((B, C), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((B, T, C), jnp.float32),
+                   st_shape, st_shape, st_shape],
         interpret=interpret_default(interpret),
     )(*operands)
+    af, bf, of = (s.reshape(B, C) for s in (af, bf, of))
     return y, (af, bf, of)

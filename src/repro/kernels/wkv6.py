@@ -32,17 +32,15 @@ from repro.kernels.common import interpret_default
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
             y_ref, sf_ref, *, T: int, C: int, N: int):
     n_chunks = T // C
-    u = u_ref[...].astype(jnp.float32)[0]                 # (1,N) -> (N,)
+    u = u_ref[0].astype(jnp.float32)[0]                   # (1,N) -> (N,)
     mask = jnp.tril(jnp.ones((C, C), jnp.float32), k=-1)  # strict lower
 
     def chunk_body(g, S):
-        # int ref indices break jax 0.4.x interpret-mode discharge; dslice
-        sl = (pl.dslice(0, 1), pl.dslice(0, 1),
-              pl.dslice(g * C, C), slice(None))
-        rc = pl.load(r_ref, sl).astype(jnp.float32)[0, 0]  # (C,N)
-        kc = pl.load(k_ref, sl).astype(jnp.float32)[0, 0]
-        vc = pl.load(v_ref, sl).astype(jnp.float32)[0, 0]
-        wc = pl.load(w_ref, sl).astype(jnp.float32)[0, 0]
+        sl = (0, 0, pl.ds(g * C, C), slice(None))
+        rc = r_ref[sl].astype(jnp.float32)                # (C,N)
+        kc = k_ref[sl].astype(jnp.float32)
+        vc = v_ref[sl].astype(jnp.float32)
+        wc = w_ref[sl].astype(jnp.float32)
         logw = jnp.log(jnp.maximum(wc, 1e-38))
         L = jnp.cumsum(logw, axis=0)                      # inclusive (C,N)
         Lprev = L - logw                                  # exclusive
@@ -56,18 +54,16 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
         y = y + jnp.dot(att, vc, preferred_element_type=jnp.float32)
         # bonus (current token)
         y = y + jnp.sum(rc * u[None] * kc, axis=-1, keepdims=True) * vc
-        pl.store(y_ref, sl, y[None, None].astype(y_ref.dtype))
+        y_ref[sl] = y.astype(y_ref.dtype)
         # state update: exponents Ltot - L <= 0 and Ltot <= 0
         Ltot = L[-1:, :]                                  # (1,N)
         k_fut = kc * jnp.exp(Ltot - L)
         return jnp.exp(Ltot[0])[:, None] * S + jnp.dot(
             k_fut.T, vc, preferred_element_type=jnp.float32)
 
-    # int ref indices break jax 0.4.x interpret-mode discharge; use dslice
-    s_sl = (pl.dslice(0, 1), pl.dslice(0, 1), slice(None), slice(None))
     S = jax.lax.fori_loop(0, n_chunks, chunk_body,
-                          pl.load(s0_ref, s_sl)[0, 0].astype(jnp.float32))
-    pl.store(sf_ref, s_sl, S[None, None])
+                          s0_ref[0, 0].astype(jnp.float32))
+    sf_ref[0, 0] = S
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -83,7 +79,8 @@ def wkv6_pallas(r, k, v, w, u, s0=None, *, chunk: int = 64,
     # head-major layout so each grid cell reads a contiguous (T, N) strip
     tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))         # (B,H,T,N)
     seq_spec = pl.BlockSpec((1, 1, T, N), lambda b, h: (b, h, 0, 0))
-    u_spec = pl.BlockSpec((1, N), lambda b, h: (h, 0))
+    # per-head bonus as (H, 1, N): a (1, N) block is then a whole axis pair
+    u_spec = pl.BlockSpec((1, 1, N), lambda b, h: (h, 0, 0))
     st_spec = pl.BlockSpec((1, 1, N, N), lambda b, h: (b, h, 0, 0))
     y, sf = pl.pallas_call(
         functools.partial(_kernel, T=T, C=C, N=N),
@@ -95,7 +92,7 @@ def wkv6_pallas(r, k, v, w, u, s0=None, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, H, N, N), jnp.float32),
         ],
         interpret=interpret_default(interpret),
-    )(tr(r), tr(k), tr(v), tr(w), u, s0)
+    )(tr(r), tr(k), tr(v), tr(w), u.reshape(H, 1, N), s0)
     return jnp.transpose(y, (0, 2, 1, 3)), sf
 
 
@@ -109,32 +106,28 @@ def _seq_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, *refs,
     refs = list(refs)
     valid_ref = refs.pop(0) if masked else None
     y_ref, sf_ref = refs
-    u = u_ref[...].astype(jnp.float32)[0]                 # (1,N) -> (N,)
+    u = u_ref[0].astype(jnp.float32)[0]                   # (1,N) -> (N,)
     snap = ((lambda x: x) if carry is None else
             (lambda x: x.astype(jnp.dtype(carry)).astype(jnp.float32)))
 
     def body(t, S):
-        # int ref indices break jax 0.4.x interpret-mode discharge; dslice
-        sl = (pl.dslice(0, 1), pl.dslice(0, 1), pl.dslice(t, 1), slice(None))
-        rt = pl.load(r_ref, sl).astype(jnp.float32)[0, 0, 0]   # (N,)
-        kt = pl.load(k_ref, sl).astype(jnp.float32)[0, 0, 0]
-        vt = pl.load(v_ref, sl).astype(jnp.float32)[0, 0, 0]
-        wt = pl.load(w_ref, sl).astype(jnp.float32)[0, 0, 0]
+        sl = (0, 0, pl.ds(t, 1), slice(None))
+        rt = r_ref[sl].astype(jnp.float32)[0]                  # (N,)
+        kt = k_ref[sl].astype(jnp.float32)[0]
+        vt = v_ref[sl].astype(jnp.float32)[0]
+        wt = w_ref[sl].astype(jnp.float32)[0]
         # exact wkv6_step: y = r @ (S + diag(u) k⊗v); S' = diag(w) S + k⊗v
         kv = kt[:, None] * vt[None, :]                         # (N,N)
         y = jnp.einsum("n,nm->m", rt, S + u[:, None] * kv)
-        pl.store(y_ref, sl, y[None, None, None].astype(y_ref.dtype))
+        y_ref[sl] = y[None].astype(y_ref.dtype)
         S_new = wt[:, None] * S + kv
         if masked:
-            ok = pl.load(valid_ref,
-                         (pl.dslice(0, 1), pl.dslice(t, 1)))[0, 0] != 0
+            ok = valid_ref[0, pl.ds(t, 1), :][0, 0] != 0
             S_new = jnp.where(ok, S_new, S)
         return snap(S_new)
 
-    s_sl = (pl.dslice(0, 1), pl.dslice(0, 1), slice(None), slice(None))
-    S = jax.lax.fori_loop(0, T, body,
-                          pl.load(s0_ref, s_sl)[0, 0].astype(jnp.float32))
-    pl.store(sf_ref, s_sl, S[None, None])
+    S = jax.lax.fori_loop(0, T, body, s0_ref[0, 0].astype(jnp.float32))
+    sf_ref[0, 0] = S
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "carry_dtype"))
@@ -153,13 +146,13 @@ def wkv6_seq_pallas(r, k, v, w, u, s0=None, *,
     # head-major layout so each grid cell reads a contiguous (T, N) strip
     tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))         # (B,H,T,N)
     seq_spec = pl.BlockSpec((1, 1, T, N), lambda b, h: (b, h, 0, 0))
-    u_spec = pl.BlockSpec((1, N), lambda b, h: (h, 0))
+    u_spec = pl.BlockSpec((1, 1, N), lambda b, h: (h, 0, 0))
     st_spec = pl.BlockSpec((1, 1, N, N), lambda b, h: (b, h, 0, 0))
-    operands = [tr(r), tr(k), tr(v), tr(w), u, s0]
+    operands = [tr(r), tr(k), tr(v), tr(w), u.reshape(H, 1, N), s0]
     in_specs = [seq_spec, seq_spec, seq_spec, seq_spec, u_spec, st_spec]
     if valid is not None:
-        operands.append(valid.astype(jnp.int32))
-        in_specs.append(pl.BlockSpec((1, T), lambda b, h: (b, 0)))
+        operands.append(valid.astype(jnp.int32).reshape(B, T, 1))
+        in_specs.append(pl.BlockSpec((1, T, 1), lambda b, h: (b, 0, 0)))
     y, sf = pl.pallas_call(
         functools.partial(_seq_kernel, T=T, masked=valid is not None,
                           carry=carry_dtype),

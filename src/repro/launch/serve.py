@@ -79,11 +79,19 @@ def greedy_decode(model, params, state, first_token, n_tokens: int,
     return jnp.concatenate(out, axis=1), state
 
 
-def sequential_decode(model, params, prompt: list[int], n_new: int):
-    """Batch-1 greedy decode of one request: feed the prompt token-by-token
+def sequential_decode(model, params, prompt: list[int], n_new: int, *,
+                      width: int = 1):
+    """Greedy decode of one request alone: feed the prompt token-by-token
     through a jitted decode_step, then argmax-chain `n_new` tokens.  This is
     the engine's bit-identity oracle (docs/serving.md) — the example and the
     scheduler tests both compare against it.
+
+    The request runs in lane 0 of a `width`-lane batch (the other lanes
+    idle on token 0); pass the engine's `max_batch`.  Lanes never mix, but
+    the compiled program's batch width is part of its numerics: XLA:CPU
+    lowers a one-row bf16 matmul as a vector-matrix loop and a wider one as
+    a GEMM, whose f32 accumulation orders differ, so a near-tie can round
+    differently.  The oracle therefore matches the engine's width.
 
     BOTH phases compile with defined rounding semantics
     (`kernels.common.exact_jit`), in lockstep with the engine: the engine
@@ -92,19 +100,16 @@ def sequential_decode(model, params, prompt: list[int], n_new: int):
     oracle must round the same way or near-tie argmaxes drift."""
     from repro.kernels.common import exact_jit
     step = exact_jit(model.decode_step)
-    prompt_step = exact_jit(model.decode_step)
-    state = model.init_decode_state(1, 0)
+    state = model.init_decode_state(width, 0)
+    feed = lambda t: jnp.zeros((width, 1), jnp.int32).at[0, 0].set(t)
     logits = None
     for t in prompt:
-        logits, state = prompt_step(params, state,
-                                    jnp.array([[t]], jnp.int32),
-                                    jnp.int32(0))
+        logits, state = step(params, state, feed(t), jnp.int32(0))
     out = []
     for _ in range(n_new):
         tok = int(jnp.argmax(logits[0, -1]))
         out.append(tok)
-        logits, state = step(params, state,
-                             jnp.array([[tok]], jnp.int32), jnp.int32(0))
+        logits, state = step(params, state, feed(tok), jnp.int32(0))
     return out
 
 
@@ -408,6 +413,8 @@ def main():
                      "resume from otherwise)")
         raise SystemExit(supervise(sys.argv[1:],
                                    max_restarts=args.max_restarts))
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.legacy or args.hw_numerics:
         serve_legacy(args.arch, smoke=args.smoke, batch=args.batch,
                      n_tokens=args.tokens, quantized=args.quantized,

@@ -76,6 +76,7 @@ def spec(cfg: ModelConfig) -> dict:
 class _Std:
     exp = staticmethod(jnp.exp)
     sigmoid = staticmethod(jax.nn.sigmoid)
+    mm = staticmethod(jnp.matmul)
     div = staticmethod(lambda a, b: a / b)
     act_q = staticmethod(lambda x: x)
 
@@ -86,6 +87,7 @@ class _Hw:
     sigmoid = staticmethod(sigmoid_pwl)
     div = staticmethod(div_lut)
     act_q = staticmethod(lambda x: uniform_fake_quant(x, 9, None))
+    mm = staticmethod(jnp.matmul)
 
 
 def _numerics(hw: bool):
@@ -102,7 +104,28 @@ def _hw_numerics_with_tables(exp_table, div_table):
         div = staticmethod(
             lambda a, b: div_lut(a, b, table=div_table))
         act_q = _Hw.act_q
+        mm = _Hw.mm
     return _HwTabled
+
+
+def _f32_sigmoid(x):
+    return jax.nn.sigmoid(x.astype(jnp.float32)).astype(x.dtype)
+
+
+def _mosaic_numerics(nm):
+    """`nm` in the forms a compiled Pallas TPU kernel lowers: matmuls
+    accumulate in f32 and the logistic runs in f32, each rounded once to
+    the compute dtype — how XLA evaluates the bf16 forms, but Mosaic takes
+    only 32-bit matmul accumulators and cannot lower a bf16 logistic.
+    Interpreted kernels keep `nm` itself, so the CPU runs stay bitwise
+    equal to the per-op program."""
+    from repro.kernels.common import f32_dot
+
+    class _Mosaic(nm):
+        mm = staticmethod(f32_dot)
+        if nm.sigmoid is jax.nn.sigmoid:
+            sigmoid = staticmethod(_f32_sigmoid)
+    return _Mosaic
 
 
 # ---------------------------------------------------------------------------
@@ -210,22 +233,22 @@ def block_decode(lp, st, x, cfg: ModelConfig, nm=_Std):
     h = L.apply_norm(lp["ln1"], x[:, None], "layernorm")[:, 0]
     p = lp["att"]
     mix = lambda m: nm.act_q(h * p[m] + att_x * (1.0 - p[m]))
-    r = mix("time_mix_r") @ p["wr"]
-    k = mix("time_mix_k") @ p["wk"]
-    v = mix("time_mix_v") @ p["wv"]
+    r = nm.mm(mix("time_mix_r"), p["wr"])
+    k = nm.mm(mix("time_mix_k"), p["wk"])
+    v = nm.mm(mix("time_mix_v"), p["wv"])
     w = jnp.exp(p["time_decay"].astype(jnp.float32))
     new_wkv, out = wkv4_step(wkv, k.astype(jnp.float32),
                              v.astype(jnp.float32), w,
                              p["time_first"].astype(jnp.float32),
                              exp=nm.exp, div=nm.div)
-    att = nm.act_q(nm.sigmoid(r) * out.astype(r.dtype)) @ p["wo"]
+    att = nm.mm(nm.act_q(nm.sigmoid(r) * out.astype(r.dtype)), p["wo"])
     x2 = x + att.astype(x.dtype)
     h2 = L.apply_norm(lp["ln2"], x2[:, None], "layernorm")[:, 0]
     p = lp["ffn"]
     mix2 = lambda m: nm.act_q(h2 * p[m] + ffn_x * (1.0 - p[m]))
-    rr = nm.sigmoid(mix2("time_mix_r") @ p["wr"])
-    kk = jnp.square(jax.nn.relu(mix2("time_mix_k") @ p["wk"]))
-    ffn = nm.act_q(rr * (nm.act_q(kk) @ p["wv"]))
+    rr = nm.sigmoid(nm.mm(mix2("time_mix_r"), p["wr"]))
+    kk = jnp.square(jax.nn.relu(nm.mm(mix2("time_mix_k"), p["wk"])))
+    ffn = nm.act_q(rr * nm.mm(nm.act_q(kk), p["wv"]))
     new_st = {"att_x": h.astype(att_x.dtype),
               "ffn_x": h2.astype(ffn_x.dtype),
               "wkv_a": new_wkv.a.astype(st["wkv_a"].dtype),
@@ -392,19 +415,22 @@ def decode_step(params, state, tokens, pos, cfg: ModelConfig, *,
     return logits, new_state
 
 
-def _fused_kernel_block(cfg: ModelConfig, nm, dt):
+def _fused_kernel_block(cfg: ModelConfig, nm, dt, interpret):
     """Per-layer body traced INSIDE a fused Pallas launch (shared by the
     per-block kernel and the whole-model megakernel): pops the optional LUT
     operands (hw numerics needs the tables as explicit VMEM inputs),
     decodes packed Δ-PoT leaves in-VMEM, then runs the same `block_decode`
     the per-op oracle uses."""
     from repro.core.quant.serving import is_packed_leaf, unpack_leaf
+    from repro.kernels.common import interpret_default
+    lower = (lambda n: n) if interpret_default(interpret) \
+        else _mosaic_numerics
 
     def kernel_block(lp, st, xx):
         lp = dict(lp)
         luts = lp.pop("_luts", None)
-        nm_k = nm if luts is None else _hw_numerics_with_tables(
-            luts["exp"], luts["div"])
+        nm_k = lower(nm if luts is None else _hw_numerics_with_tables(
+            luts["exp"], luts["div"]))
         lp = jax.tree_util.tree_map(
             lambda l: unpack_leaf(l).astype(dt) if is_packed_leaf(l) else l,
             lp, is_leaf=is_packed_leaf)
@@ -443,7 +469,7 @@ def decode_step_fused(params, state, tokens, pos, cfg: ModelConfig, *,
     x = jnp.take(params["embed"], tokens[:, 0], axis=0).astype(dt)
     x = L.apply_norm(params["ln0"], x[:, None], "layernorm")[:, 0]
 
-    kernel_block = _fused_kernel_block(cfg, nm, dt)
+    kernel_block = _fused_kernel_block(cfg, nm, dt, interpret)
     blocks = broadcast_packed_scales(params["blocks"], cfg.n_layers)
     if hw:
         # LUTs as scanned operands (per-layer slices are identical views)
@@ -521,7 +547,7 @@ def decode_step_fused_model(params, state, tokens, pos, cfg: ModelConfig, *,
         luts = _lut_operands(1)   # leading-1: resident across the grid
         blocks = {**blocks, "_luts": luts}
     x, new_state = fused_model_decode(
-        _fused_kernel_block(cfg, nm, dt), x, blocks, state, bb=bb,
+        _fused_kernel_block(cfg, nm, dt, interpret), x, blocks, state, bb=bb,
         weights=weights, interpret=interpret)
     x = L.apply_norm(params["ln_f"], x[:, None], "layernorm")
     logits = x @ unpack_leaf(params["head"]).astype(x.dtype)

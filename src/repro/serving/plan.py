@@ -283,6 +283,18 @@ class ExecutionPlan:
                 x.shape, self.mesh)
         return jax.device_put(x, sh)
 
+    def _pin_state(self, state):
+        """Keep a program's output state in the pool's sharding.  Left to
+        itself the compiler may choose another layout (a Pallas call's
+        outputs come back replicated), and the next program, compiled for
+        the pool's layout, would refuse the state."""
+        if self.mesh is None:
+            return state
+        from repro.parallel.sharding import pool_shardings
+        return jax.lax.with_sharding_constraint(
+            state, pool_shardings(self.model.decode_state_axes(), state,
+                                  self.mesh))
+
     # -- program cache -----------------------------------------------------
 
     def _key(self, kind: str, batch: int):
@@ -383,7 +395,8 @@ class ExecutionPlan:
         def decode(params, state, tokens, mask):
             self.trace_counts[count_key] += 1  # increments only on trace
             logits, new_state = step(params, state, tokens)
-            return logits, masked_state_commit(new_state, state, mask, axes)
+            return logits, self._pin_state(
+                masked_state_commit(new_state, state, mask, axes))
 
         # exact_jit like every other token-producing program: defined
         # rounding semantics make the speculative verifier's bit-parity
@@ -422,7 +435,9 @@ class ExecutionPlan:
                 # fused chunked path: chunk-shaped matmuls + on-chip WKV
                 # scan; packed Δ-PoT leaves decode INSIDE the kernels, so
                 # no maybe_unpack here — codes cross HBM, not bf16
-                return model.prefill_chunk(params, state, tokens, valid)
+                new_state, last = model.prefill_chunk(params, state, tokens,
+                                                      valid)
+                return self._pin_state(new_state), last
             p = maybe_unpack(params, quantized)
 
             def body(carry, xs):
@@ -437,7 +452,7 @@ class ExecutionPlan:
             last0 = jnp.zeros(ab_logits.shape, ab_logits.dtype)
             (state, last), _ = jax.lax.scan(
                 body, (state, last0), (tokens.T, valid.T))
-            return state, last
+            return self._pin_state(state), last
 
         # BOTH prefill structures compile with defined rounding semantics
         # (exact_jit: no excess-precision folding) — the property that
@@ -538,7 +553,7 @@ class ExecutionPlan:
                 # head: every valid window token's logits in one call
                 new_state, logits = model.prefill_chunk_logits(
                     params, state, tokens, valid)
-                return logits, new_state
+                return logits, self._pin_state(new_state)
             p = maybe_unpack(params, quantized)
 
             def body(state, xs):
@@ -551,7 +566,7 @@ class ExecutionPlan:
                 return state, row
 
             state, rows = jax.lax.scan(body, state, (tokens.T, valid.T))
-            return jnp.swapaxes(rows, 0, 1), state      # (S, K, V)
+            return jnp.swapaxes(rows, 0, 1), self._pin_state(state)
 
         # exact_jit (same rounding semantics as the decode step — the
         # losslessness theorem); NO donation: the caller's pre-verify
@@ -567,7 +582,8 @@ class ExecutionPlan:
 
         def rollback(committed, snapshot, reject):
             self.trace_counts["rollback"] += 1  # increments only on trace
-            return masked_state_commit(snapshot, committed, reject, axes)
+            return self._pin_state(
+                masked_state_commit(snapshot, committed, reject, axes))
 
         # `committed` is consumed (donated); the snapshot survives — the
         # scheduler re-advances rejected lanes from the rolled-back state
@@ -594,6 +610,20 @@ def _registry_arch_id(cfg_name: str, smoke: bool) -> str:
         if cfg.name == cfg_name:
             return arch
     return base     # unregistered/ad-hoc config: best effort
+
+
+def _check_megakernel_fits(decode_params):
+    """Refuse a megakernel plan whose weight stream cannot fit the chip's
+    VMEM (kernels.fused_decode.megakernel_vmem_bytes) at construction —
+    never a silent fallback.  Interpreted kernels (the CPU backend) model
+    no VMEM, so only a compiling backend is checked."""
+    from repro.core.quant.serving import FusedLayerStack
+    from repro.kernels.common import interpret_default
+    from repro.kernels.fused_decode import megakernel_vmem_bytes
+    stack = decode_params.get("blocks") if isinstance(decode_params, dict) \
+        else None
+    if isinstance(stack, FusedLayerStack) and not interpret_default(None):
+        megakernel_vmem_bytes(stack, "stream")
 
 
 def build_plan(model: Model | str, params: Any = None, *,
@@ -700,6 +730,8 @@ def build_plan(model: Model | str, params: Any = None, *,
         # step unpacks in-trace exactly like the per-op decode path
         draft=None if spec_path is None or spec_path.k == 1 else
         model.truncate_params(params, spec_path.draft_depth))
+    if decode_name == "model":
+        _check_megakernel_fits(prepared.decode)
     plan = ExecutionPlan(model, prepared, decode_desc, prefill_desc,
                          prefill_chunk=prefill_chunk, max_len=max_len,
                          state_dtype=state_dtype, mesh=mesh,

@@ -67,6 +67,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro.kernels.common import CompileError
 from repro.runtime.monitor import DegradedMode, EngineCrash
 from repro.serving.slo import (SHED, Overloaded, SchedulerHang,
                                ServingSLO)
@@ -622,15 +623,19 @@ class Scheduler:
         tracking: below `path_fault_limit` the primary is retried; at the
         limit the path is demoted to its per-op twin (bit-identical
         stream, DegradedMode event) for the life of the scheduler.  With
-        no twin available the error propagates.  See the ctor comment
-        for the donation caveat on retries."""
+        no twin available the error propagates.  Only a program that has
+        compiled can fault: a trace or compile failure (`CompileError`)
+        always propagates.  See the ctor comment for the donation caveat
+        on retries."""
         if kind in self._demoted:
             return self._fallback(kind)(*args)
         while True:
             try:
                 out = fn(*args)
-            except (EngineCrash, KeyboardInterrupt):
-                raise               # injected crashes are not path faults
+            except (EngineCrash, KeyboardInterrupt, CompileError):
+                # injected crashes are not path faults, and a program that
+                # cannot compile is a bug to surface, not a device fault
+                raise
             except Exception as e:
                 n = self._path_failures[kind] = \
                     self._path_failures.get(kind, 0) + 1

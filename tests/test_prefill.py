@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jcore
 
 from repro.core.quant.serving import pack_params
 from repro.kernels.common import exact_jit
@@ -185,9 +186,9 @@ def _outside_kernel_primitives(jaxpr, acc):
         for v in eqn.params.values():
             vals = v if isinstance(v, (list, tuple)) else [v]
             for e in vals:
-                if isinstance(e, jax.core.ClosedJaxpr):
+                if isinstance(e, jcore.ClosedJaxpr):
                     _outside_kernel_primitives(e.jaxpr, acc)
-                elif isinstance(e, jax.core.Jaxpr):
+                elif isinstance(e, jcore.Jaxpr):
                     _outside_kernel_primitives(e, acc)
     return acc
 
@@ -204,9 +205,9 @@ def _pallas_consumes_uint8(jaxpr):
             for v in eqn.params.values():
                 vals = v if isinstance(v, (list, tuple)) else [v]
                 for e in vals:
-                    if isinstance(e, jax.core.ClosedJaxpr):
+                    if isinstance(e, jcore.ClosedJaxpr):
                         walk(e.jaxpr)
-                    elif isinstance(e, jax.core.Jaxpr):
+                    elif isinstance(e, jcore.Jaxpr):
                         walk(e)
     walk(jaxpr)
     return found[0]
@@ -495,9 +496,9 @@ def _outside_kernel_flat_gather(jaxpr):
             for v in eqn.params.values():
                 vals = v if isinstance(v, (list, tuple)) else [v]
                 for e in vals:
-                    if isinstance(e, jax.core.ClosedJaxpr):
+                    if isinstance(e, jcore.ClosedJaxpr):
                         walk(e.jaxpr)
-                    elif isinstance(e, jax.core.Jaxpr):
+                    elif isinstance(e, jcore.Jaxpr):
                         walk(e)
     walk(jaxpr)
     return found[0]

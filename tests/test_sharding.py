@@ -34,10 +34,7 @@ class TestSpecRules:
     @staticmethod
     def _abstract_mesh(sizes, names):
         from jax.sharding import AbstractMesh
-        try:   # jax 0.4.x: one tuple of (name, size) pairs
-            return AbstractMesh(tuple(zip(names, sizes)))
-        except TypeError:   # jax >= 0.5: (axis_sizes, axis_names)
-            return AbstractMesh(sizes, names)
+        return AbstractMesh(sizes, names)
 
     def test_fsdp_tp(self):
         spec = spec_for_axes(("fsdp", "tp"), (4096, 4096), self._mesh())

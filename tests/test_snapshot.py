@@ -431,6 +431,29 @@ def test_path_fallback_demotes_after_limit(rwkv4):
     assert h.outcome == "finished" and len(h.tokens) == 3
 
 
+def test_compile_error_propagates_without_demotion(rwkv4):
+    """A program that fails to trace or compile is a bug, not a device
+    fault: it raises out of the scheduler on the first call — no retry, no
+    demotion to the per-op twin that would hide it."""
+    from repro.kernels.common import CompileError, exact_jit
+    model, params = rwkv4
+    eng = ServingEngine(model, params=params, prefill_chunk=4, max_batch=2,
+                        fused_decode=True, path_fault_limit=2)
+    calls = {"n": 0}
+
+    def broken(state, tokens, mask):
+        calls["n"] += 1
+        raise ValueError("trace-time bug")
+
+    eng.scheduler.decode_fn = exact_jit(broken)
+    _submit_all(eng, _prompts(2))
+    with pytest.raises(CompileError):
+        eng.run()
+    assert calls["n"] == 1                       # traced once, not retried
+    assert eng.scheduler.demoted == frozenset()
+    assert eng.counters.path_fallbacks == 0
+
+
 def test_path_fault_below_limit_retries_without_demotion(rwkv4):
     model, params = rwkv4
     prompts = _prompts(2)
