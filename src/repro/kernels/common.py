@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.runtime.monitor import span
+
 
 class CompileError(RuntimeError):
     """A program failed to trace, lower or compile.  That is a fault of the
@@ -35,7 +37,9 @@ def exact_jit(fn, donate_argnums=()):
     scan-of-`decode_step` oracle (the serving engine compiles both its
     prefill programs through this; see docs/serving.md).  Compilation is
     AOT (`lower().compile()`) because compiler options only attach there;
-    the wrapper lowers lazily on first call and caches the executable.
+    the wrapper lowers lazily on first call and caches the executable;
+    a lowering and compile runs under a `serve.compile` host span, so a
+    compile during serving shows in a profiler trace.
     """
     jitted = jax.jit(fn, donate_argnums=donate_argnums)
     cache = {}
@@ -48,8 +52,10 @@ def exact_jit(fn, donate_argnums=()):
                     for leaf in jax.tree_util.tree_leaves(args))
         if key not in cache:
             try:
-                cache[key] = jitted.lower(*args).compile(
-                    compiler_options={"xla_allow_excess_precision": False})
+                with span("serve.compile"):
+                    cache[key] = jitted.lower(*args).compile(
+                        compiler_options={
+                            "xla_allow_excess_precision": False})
             except Exception as e:
                 raise CompileError(
                     f"{getattr(fn, '__name__', fn)!s} failed to compile: "

@@ -38,6 +38,11 @@ that policy, testable in-process via FailureInjector.
                       prefill path demoted to its per-op twin)
   EngineCrash       — the injected in-process crash (crash_at_tick)
   TrainingSupervisor— retry-with-restore driver around a step function
+  span / trace_gc   — host spans on the profiler's clock: the serving
+                      scheduler names each phase of a tick `serve.*`,
+                      and `trace_gc` puts every Python collection under
+                      `serve.gc`, so a device trace can say what the host
+                      was doing while the chip waited
 
 `ServingCounters` also exposes `state_dict()`/`load_state()` so the
 serving snapshot layer (repro.serving.snapshot) can carry telemetry
@@ -49,9 +54,42 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import math
 import time
 from typing import Any, Callable, Optional
+
+from jax.profiler import TraceAnnotation
+
+
+def span(name: str) -> TraceAnnotation:
+    """A host span named `name`, recorded into the profiler's trace (the
+    `.xplane.pb` that holds the device's events, on the same clock) while
+    a profiler session is active.  With none active it costs about a
+    microsecond, so spans stay on with no switch."""
+    return TraceAnnotation(name)
+
+
+class _GcSpans:
+    """`gc.callbacks` hook: a `serve.gc` span from each collection's
+    start to its stop."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: dict):
+        if phase == "start":
+            self._open = span("serve.gc")
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+def trace_gc():
+    """Install the `serve.gc` collection hook, once per process."""
+    if not any(isinstance(cb, _GcSpans) for cb in gc.callbacks):
+        gc.callbacks.append(_GcSpans())
 
 
 def percentile(xs: list, q: float) -> float:
@@ -85,6 +123,7 @@ class ServingCounters:
         self._admit_t: dict[int, float] = {}
         self._prefill_ticks: dict[int, int] = {}
         self.ttft_s: list[float] = []      # enqueue -> first token
+        self.queue_wait_s: list[float] = []  # enqueue -> admission
         self.latency_s: list[float] = []   # enqueue -> completion
         # time-to-first-token decomposition: how many prefill calls each
         # request's prompt took, and the admit -> first-token wall time
@@ -142,6 +181,14 @@ class ServingCounters:
         # give the bench its latency-vs-occupancy axis
         self._active_sum = 0
         self._queued_sum = 0
+        # device program calls of the plain (non-speculative) tick: decode
+        # calls with the lanes they decoded out of the lanes they offered,
+        # prefill calls, and the summed wall time of the ticks
+        self.decode_calls = 0
+        self.decode_lanes = 0
+        self.decode_slots = 0
+        self.prefill_calls = 0
+        self.tick_wall_s = 0.0
 
     def now(self) -> float:
         """The counters' clock (injectable) — the scheduler times its
@@ -155,12 +202,25 @@ class ServingCounters:
 
     def on_admit(self, rid: int):
         self.admitted += 1
-        self._admit_t[rid] = self._clock()
+        now = self._admit_t[rid] = self._clock()
+        if rid in self._enqueue_t:
+            self.queue_wait_s.append(now - self._enqueue_t[rid])
 
     def on_prefill(self, rid: int, n_tokens: int):
         """One prefill call absorbed `n_tokens` of request `rid`'s prompt."""
         self.prefill_tokens += n_tokens
         self._prefill_ticks[rid] = self._prefill_ticks.get(rid, 0) + 1
+
+    def on_prefill_call(self):
+        """One prefill program call (every prefilling lane's chunk)."""
+        self.prefill_calls += 1
+
+    def on_decode_call(self, lanes: int, slots: int):
+        """One decode program call over `slots` pool lanes, `lanes` of
+        them decoding."""
+        self.decode_calls += 1
+        self.decode_lanes += lanes
+        self.decode_slots += slots
 
     def on_cache_probe(self, rid: int, *, hit: bool, n_cached: int = 0,
                        probe_s: float = 0.0, copy_s: float = 0.0):
@@ -296,8 +356,9 @@ class ServingCounters:
                                     if dataclasses.is_dataclass(event)
                                     else dict(event))
 
-    def on_tick(self, *, active: int, queued: int):
+    def on_tick(self, *, active: int, queued: int, wall_s: float = 0.0):
         self.ticks += 1
+        self.tick_wall_s += wall_s
         self.peak_active = max(self.peak_active, active)
         self.peak_queued = max(self.peak_queued, queued)
         self._active_sum += active
@@ -337,6 +398,14 @@ class ServingCounters:
                 if self.ticks else 0.0,
             "mean_queue_depth": self._queued_sum / self.ticks
                 if self.ticks else 0.0,
+            "queue_wait_p50_s": percentile(self.queue_wait_s, 0.50),
+            "queue_wait_p99_s": percentile(self.queue_wait_s, 0.99),
+            "decode_calls": self.decode_calls,
+            "decode_lanes": self.decode_lanes,
+            "mean_decode_occupancy": self.decode_lanes / self.decode_slots
+                if self.decode_slots else 0.0,
+            "prefill_calls": self.prefill_calls,
+            "tick_wall_s": self.tick_wall_s,
             "mean_prefill_ticks": mean(self.prefill_ticks),
             "mean_prefill_s": mean(self.prefill_s),
             "peak_active_slots": self.peak_active,
@@ -378,9 +447,10 @@ class ServingCounters:
         "backpressured", "cache_errors", "budget_deferred_tokens",
         "snapshots_written", "restores", "resumed_lanes",
         "quarantined_lanes", "checksum_failures", "path_fallbacks",
-        "_active_sum", "_queued_sum")
+        "_active_sum", "_queued_sum", "decode_calls", "decode_lanes",
+        "decode_slots", "prefill_calls", "tick_wall_s")
     _LIST_FIELDS = (
-        "ttft_s", "latency_s", "prefill_ticks", "prefill_s",
+        "ttft_s", "queue_wait_s", "latency_s", "prefill_ticks", "prefill_s",
         "cache_probe_s", "state_copy_s", "itl_s", "snapshot_wall_s",
         "degraded_events")
     _TIME_DICT_FIELDS = (    # rid -> absolute clock time, rebased on load
@@ -409,10 +479,11 @@ class ServingCounters:
         """Install a `state_dict` capture, rebasing clock anchors onto
         this counters object's own clock."""
         now = self._clock()
+        # a capture from before a field existed leaves it as constructed
         for f in self._COUNTER_FIELDS:
-            setattr(self, f, state[f])
+            setattr(self, f, state.get(f, getattr(self, f)))
         for f in self._LIST_FIELDS:
-            setattr(self, f, list(state[f]))
+            setattr(self, f, list(state.get(f, getattr(self, f))))
         self.t_start = now - state["elapsed_s"]
         for f in self._TIME_DICT_FIELDS:
             setattr(self, f, {int(r): now - ago

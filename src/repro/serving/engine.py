@@ -38,7 +38,7 @@ from typing import Any, Iterator, Optional
 import jax.numpy as jnp
 
 from repro.models.registry import Model
-from repro.runtime.monitor import ServingCounters
+from repro.runtime.monitor import ServingCounters, trace_gc
 from repro.serving.plan import ExecutionPlan, build_plan
 from repro.serving.prefix_cache import PrefixCache, PrefixCacheConfig
 from repro.serving.scheduler import Request, Scheduler
@@ -196,6 +196,7 @@ class ServingEngine:
         self.params = plan.prepared.raw
         self.counters = counters if counters is not None else \
             ServingCounters()
+        trace_gc()
         self.pool = SlotStatePool(self.model, max_batch,
                                   max_len=plan.max_len,
                                   dtype=plan.state_dtype,

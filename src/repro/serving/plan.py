@@ -84,10 +84,14 @@ def maybe_unpack(params, quantized: bool):
     """In-trace Δ-PoT decode for the per-op paths: packed trees unpack
     INSIDE the jit (uint8 codes cross HBM; the exp2 decode fuses into the
     consumer matmuls).  Fused paths never call this — their descriptors
-    carry `fused=True` and the kernels decode per leaf."""
+    carry `fused=True` and the kernels decode per leaf.  The unpack runs
+    under the name scope `w8_unpack`, which the compiled program's
+    operations carry in their metadata, so a device trace can tell the
+    decode of the weights from the matmuls that use them."""
     if quantized:
         from repro.core.quant.serving import unpack_params
-        return unpack_params(params)
+        with jax.named_scope("w8_unpack"):
+            return unpack_params(params)
     return params
 
 

@@ -68,7 +68,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from repro.kernels.common import CompileError
-from repro.runtime.monitor import DegradedMode, EngineCrash
+from repro.runtime.monitor import DegradedMode, EngineCrash, span
 from repro.serving.slo import (SHED, Overloaded, SchedulerHang,
                                ServingSLO)
 
@@ -393,26 +393,37 @@ class Scheduler:
     def tick(self) -> bool:
         """One scheduling round; returns True while work remains.
         Ticks are numbered from 1 (`ServingFaultInjector` schedules are
-        keyed on this number and drained at the top of the tick)."""
-        self._tick_no += 1
-        if self.fault_injector is not None:
-            self._apply_faults()
-        self._sentinel_sweep()
-        self._expire_deadlines()
-        self._admit()
-        self._prefill_tick()
-        if self.spec_k:
-            self._spec_tick()
-        else:
-            self._decode_tick()
-        if self.counters is not None:
-            self.counters.on_tick(active=len(self.slots),
-                                  queued=len(self.queue))
-        if self.after_tick is not None:
-            # tick-boundary hook (snapshots): fires with every boundary
-            # invariant holding — no speculation in flight, no lease
-            # held, all lane states committed
-            self.after_tick(self._tick_no)
+        keyed on this number and drained at the top of the tick).
+
+        Each phase of the plain tick runs under a `serve.*` host span
+        (`runtime.monitor.span`): `serve.tick` around it all, then
+        `serve.admit`, and inside `serve.prefill` / `serve.decode` the
+        steps `serve.inputs` -> `serve.dispatch` -> `serve.fetch` ->
+        `serve.sample` -> `serve.emit`.  In a profiler trace they say
+        what the host was doing while the device waited."""
+        t0 = self._now()
+        with span("serve.tick"):
+            self._tick_no += 1
+            if self.fault_injector is not None:
+                self._apply_faults()
+            with span("serve.admit"):
+                self._sentinel_sweep()
+                self._expire_deadlines()
+                self._admit()
+            self._prefill_tick()
+            if self.spec_k:
+                self._spec_tick()
+            else:
+                self._decode_tick()
+            if self.counters is not None:
+                self.counters.on_tick(active=len(self.slots),
+                                      queued=len(self.queue),
+                                      wall_s=self._now() - t0)
+            if self.after_tick is not None:
+                # tick-boundary hook (snapshots): fires with every
+                # boundary invariant holding — no speculation in flight,
+                # no lease held, all lane states committed
+                self.after_tick(self._tick_no)
         return bool(self.queue or self.slots)
 
     def run(self, *, max_idle_ticks: Optional[int] = None):
@@ -793,76 +804,91 @@ class Scheduler:
                       if m.phase == PREFILL]
         if not prefilling:
             return
-        S, C = self.pool.max_slots, self.prefill_chunk
-        quota = self._prefill_quota
-        if (quota is not None and quota < len(prefilling)
-                and any(m.phase == DECODE for m in self.slots.values())):
-            # prefill budget (ServingSLO.prefill_budget): while lanes are
-            # DECODING, only `quota` prefilling lanes join this tick's
-            # call — highest priority first, then earliest deadline, then
-            # admission order.  The (S, C) program shape never changes
-            # (deferred lanes just keep empty validity rows), so the
-            # compiled-program cache is untouched; with no decode lane
-            # live there is no inter-token latency to protect and
-            # prefill runs unthrottled.
-            prefilling.sort(key=lambda sm: (
-                -sm[1].req.priority,
-                sm[1].deadline_t if sm[1].deadline_t is not None
-                else float("inf"),
-                sm[1].seq))
-            deferred = prefilling[quota:]
-            prefilling = prefilling[:quota]
-            if self.counters is not None and deferred:
-                self.counters.on_budget_defer(sum(
-                    min(len(m.req.prompt) - m.n_prefilled, C)
-                    for _, m in deferred))
-        toks = np.zeros((S, C), np.int32)
-        valid = np.zeros((S, C), bool)
-        fresh = np.zeros((S,), bool)
-        parts = {}
-        for slot, meta in prefilling:
-            part = meta.req.prompt[
-                meta.n_prefilled:meta.n_prefilled + C]
-            toks[slot, :len(part)] = part
-            valid[slot, :len(part)] = True
-            fresh[slot] = meta.fresh
-            parts[slot] = len(part)
-        self.pool.state, last_logits = self._run_program(
-            "prefill", self.prefill_fn, self.pool.state, toks, valid, fresh)
-        finishing = []
-        for slot, meta in prefilling:
-            meta.fresh = False
-            meta.n_prefilled += parts[slot]
-            self._progress += parts[slot]
+        with span("serve.prefill"):
+            S, C = self.pool.max_slots, self.prefill_chunk
+            with span("serve.inputs"):
+                quota = self._prefill_quota
+                if (quota is not None and quota < len(prefilling)
+                        and any(m.phase == DECODE
+                                for m in self.slots.values())):
+                    # prefill budget (ServingSLO.prefill_budget): while lanes
+                    # are DECODING, only `quota` prefilling lanes join this
+                    # tick's call — highest priority first, then earliest
+                    # deadline, then admission order.  The (S, C) program
+                    # shape never changes (deferred lanes just keep empty
+                    # validity rows), so the compiled-program cache is
+                    # untouched; with no decode lane live there is no
+                    # inter-token latency to protect and prefill runs
+                    # unthrottled.
+                    prefilling.sort(key=lambda sm: (
+                        -sm[1].req.priority,
+                        sm[1].deadline_t if sm[1].deadline_t is not None
+                        else float("inf"),
+                        sm[1].seq))
+                    deferred = prefilling[quota:]
+                    prefilling = prefilling[:quota]
+                    if self.counters is not None and deferred:
+                        self.counters.on_budget_defer(sum(
+                            min(len(m.req.prompt) - m.n_prefilled, C)
+                            for _, m in deferred))
+                toks = np.zeros((S, C), np.int32)
+                valid = np.zeros((S, C), bool)
+                fresh = np.zeros((S,), bool)
+                parts = {}
+                for slot, meta in prefilling:
+                    part = meta.req.prompt[
+                        meta.n_prefilled:meta.n_prefilled + C]
+                    toks[slot, :len(part)] = part
+                    valid[slot, :len(part)] = True
+                    fresh[slot] = meta.fresh
+                    parts[slot] = len(part)
+            with span("serve.dispatch"):
+                self.pool.state, last_logits = self._run_program(
+                    "prefill", self.prefill_fn, self.pool.state, toks, valid,
+                    fresh)
             if self.counters is not None:
-                self.counters.on_prefill(meta.req.rid, parts[slot])
-            if self.prefix_cache is not None:
-                self._cache_capture(slot, meta)
-            if meta.n_prefilled == len(meta.req.prompt):
-                # prompt fully absorbed: the last prompt token's logits
-                # yield the first generated token; the slot joins the
-                # fused decode batch from this tick on.
-                meta.phase = DECODE
-                finishing.append((slot, meta))
-        if finishing:
-            rows = np.asarray(last_logits[:, -1], np.float32)
-            self._emit([(s, m, rows[s]) for s, m in finishing])
+                self.counters.on_prefill_call()
+            finishing = []
+            for slot, meta in prefilling:
+                meta.fresh = False
+                meta.n_prefilled += parts[slot]
+                self._progress += parts[slot]
+                if self.counters is not None:
+                    self.counters.on_prefill(meta.req.rid, parts[slot])
+                if self.prefix_cache is not None:
+                    self._cache_capture(slot, meta)
+                if meta.n_prefilled == len(meta.req.prompt):
+                    # prompt fully absorbed: the last prompt token's logits
+                    # yield the first generated token; the slot joins the
+                    # fused decode batch from this tick on.
+                    meta.phase = DECODE
+                    finishing.append((slot, meta))
+            if finishing:
+                with span("serve.fetch"):
+                    rows = np.asarray(last_logits[:, -1], np.float32)
+                self._emit([(s, m, rows[s]) for s, m in finishing])
 
     def _decode_tick(self):
         active = [(s, m) for s, m in self.slots.items()
                   if m.phase == DECODE]
         if not active:
             return
-        S = self.pool.max_slots
-        toks = np.zeros((S, 1), np.int32)
-        mask = np.zeros((S,), bool)
-        for slot, meta in active:
-            toks[slot, 0] = meta.next_token
-            mask[slot] = True
-        logits, self.pool.state = self._run_program(
-            "decode", self.decode_fn, self.pool.state, toks, mask)
-        rows = np.asarray(logits[:, -1], np.float32)
-        self._emit([(s, m, rows[s]) for s, m in active])
+        with span("serve.decode"):
+            S = self.pool.max_slots
+            with span("serve.inputs"):
+                toks = np.zeros((S, 1), np.int32)
+                mask = np.zeros((S,), bool)
+                for slot, meta in active:
+                    toks[slot, 0] = meta.next_token
+                    mask[slot] = True
+            with span("serve.dispatch"):
+                logits, self.pool.state = self._run_program(
+                    "decode", self.decode_fn, self.pool.state, toks, mask)
+            if self.counters is not None:
+                self.counters.on_decode_call(len(active), S)
+            with span("serve.fetch"):
+                rows = np.asarray(logits[:, -1], np.float32)
+            self._emit([(s, m, rows[s]) for s, m in active])
 
     def _spec_tick(self):
         """The speculative decode tick: draft -> verify -> accept.
@@ -979,25 +1005,28 @@ class Scheduler:
         """Sample + book-keep one tick's emitting slots.  Sampling is the
         batched `sample_tokens` (ONE argmax call for the whole block);
         bookkeeping stays per-slot."""
-        toks = sample_tokens(
-            np.stack([row for _, _, row in emitting]),
-            [meta for _, meta, _ in emitting])
-        for (slot, meta, _), tok in zip(emitting, toks):
-            req, tok = meta.req, int(tok)
-            meta.generated.append(tok)
-            meta.next_token = tok
-            self._progress += 1
-            if self.counters is not None:
-                self.counters.on_token(req.rid,
-                                       first=len(meta.generated) == 1)
-            self.on_token(req, tok)
-            self._check_token_fault(req.rid)
-            if slot not in self.slots or self.slots[slot] is not meta:
-                continue    # evicted by its token callback / a fault
-            done = (len(meta.generated) >= req.max_new_tokens or
-                    (req.eos_token is not None and tok == req.eos_token))
-            if done:
-                self._retire(slot, meta)
+        with span("serve.sample"):
+            toks = sample_tokens(
+                np.stack([row for _, _, row in emitting]),
+                [meta for _, meta, _ in emitting])
+        with span("serve.emit"):
+            for (slot, meta, _), tok in zip(emitting, toks):
+                req, tok = meta.req, int(tok)
+                meta.generated.append(tok)
+                meta.next_token = tok
+                self._progress += 1
+                if self.counters is not None:
+                    self.counters.on_token(req.rid,
+                                           first=len(meta.generated) == 1)
+                self.on_token(req, tok)
+                self._check_token_fault(req.rid)
+                if slot not in self.slots or self.slots[slot] is not meta:
+                    continue    # evicted by its token callback / a fault
+                done = (len(meta.generated) >= req.max_new_tokens or
+                        (req.eos_token is not None
+                         and tok == req.eos_token))
+                if done:
+                    self._retire(slot, meta)
 
     def _check_token_fault(self, rid: int):
         """ServingFaultInjector "evict_on_token": evict `rid` from inside
