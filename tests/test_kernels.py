@@ -111,6 +111,64 @@ class TestWkv4Kernel:
             np.asarray(y_full),
             np.asarray(jnp.concatenate([y1, y2], 1)), rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("mode", ["plain", "masked", "masked-bf16",
+                                      "masked-bf16-lut"])
+    @pytest.mark.parametrize("C", [64, 256, 768])
+    @pytest.mark.parametrize("B", [1, 3, 8, 12, 32])
+    def test_bitwise_vs_step_scan(self, B, C, mode):
+        """Every batch tiling (fewer lanes than the f32 sublanes, whole
+        sublane blocks, a batch padded to them) is bit-identical to a
+        `lax.scan` of `wkv4_step` with the serving path's masked commits
+        and dtype snaps, for random prefix masks."""
+        from repro.core.approx.units import DIV_LUT_TABLE, EXP_LUT_TABLE
+        from repro.core.approx import div_lut, exp_lut
+        from repro.core.wkv import WKV4State, wkv4_step
+        from repro.kernels.common import exact_jit
+        T = 12
+        rng = np.random.default_rng(B * 1000 + C)
+        f32 = lambda x: jnp.asarray(x, jnp.float32)
+        k, v = f32(rng.normal(size=(2, B, T, C)) * 2)
+        w, u = f32(np.exp(rng.normal(size=C))), f32(rng.normal(size=C))
+        a0, o0 = f32(rng.normal(size=(2, B, C)))
+        b0 = f32(np.abs(rng.normal(size=(B, C))))
+        n = rng.integers(0, T + 1, B)
+        valid = jnp.asarray(np.arange(T)[None] < n[:, None])
+        carry = "bfloat16" if "bf16" in mode else None
+        snap = ((lambda x: x) if carry is None else
+                (lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)))
+        kw, exp, div = {}, jnp.exp, None
+        if "lut" in mode:
+            et = f32(np.reshape(EXP_LUT_TABLE, -1))
+            dt = f32(np.reshape(DIV_LUT_TABLE, -1))
+            kw = {"exp_table": et, "div_table": dt}
+            exp = lambda x: exp_lut(x, table=et)
+            div = lambda x, y: div_lut(x, y, table=dt)
+
+        def oracle(k, v, a0, b0, o0, valid):
+            def body(st, xs):
+                kt, vt, ok = xs
+                new, y = wkv4_step(st, kt, vt, w, u, exp=exp, div=div)
+                if mode != "plain":
+                    new = [jnp.where(ok[:, None], nw, old)
+                           for nw, old in zip(new, st)]
+                return WKV4State(*map(snap, new)), y
+            fin, ys = jax.lax.scan(
+                body, WKV4State(a0, b0, o0),
+                (jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1), valid.T))
+            return jnp.swapaxes(ys, 0, 1), tuple(fin)
+
+        def kernel(k, v, a0, b0, o0, valid):
+            return wkv4_pallas(k, v, w, u, a0, b0, o0, carry_dtype=carry,
+                               valid=None if mode == "plain" else valid,
+                               **kw)
+
+        args = (k, v, a0, b0, o0, valid)
+        got = jax.tree_util.tree_leaves(exact_jit(kernel)(*args))
+        want = jax.tree_util.tree_leaves(exact_jit(oracle)(*args))
+        for g, x in zip(got, want):
+            assert g.shape == x.shape
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(x))
+
 
 class TestWkv6Kernel:
     @pytest.mark.parametrize("B,T,H,N,chunk", [

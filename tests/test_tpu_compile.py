@@ -54,16 +54,20 @@ def _compile(fn, *args):
     return compiled
 
 
-def test_wkv4_sequence_kernel(one_chip):
-    """The masked WKV-4 scan of the chunked prefill, bf16-snapped carry."""
+@pytest.mark.parametrize("b,t,c", [(B, T, D), (32, 64, 768),
+                                   (32, 64, 2048)])
+def test_wkv4_sequence_kernel(one_chip, b, t, c):
+    """The masked WKV-4 scan of the chunked prefill, bf16-snapped carry:
+    at this file's batch of 8, and at the benchmark cells' shapes, 32
+    slots x 64-token chunks at 169M and 1.5B widths."""
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                               sharding=one_chip)
-    valid = jax.ShapeDtypeStruct((B, T), jnp.bool_, sharding=one_chip)
-    _compile(lambda k, v, w, u, a, b, o, ok: wkv4_pallas(
-        k, v, w, u, a, b, o, valid=ok, carry_dtype="bfloat16",
+    valid = jax.ShapeDtypeStruct((b, t), jnp.bool_, sharding=one_chip)
+    _compile(lambda k, v, w, u, a, b_, o, ok: wkv4_pallas(
+        k, v, w, u, a, b_, o, valid=ok, carry_dtype="bfloat16",
         interpret=False),
-        f32(B, T, D), f32(B, T, D), f32(D), f32(D),
-        f32(B, D), f32(B, D), f32(B, D), valid)
+        f32(b, t, c), f32(b, t, c), f32(c), f32(c),
+        f32(b, c), f32(b, c), f32(b, c), valid)
 
 
 @pytest.mark.parametrize("m,k,n", [(B * T, D, D), (B * T, D, F),
