@@ -33,7 +33,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 CONTROL_DTYPE = "float8_e4m3fn"
 
 
-def readings(cfg: dict, traffic: dict, seed: int, requests) -> dict:
+def readings(cfg: dict, fam, traffic: dict, seed: int, requests) -> dict:
     """The widest and the mean gap of the program and of the control on
     one sample."""
     import numpy as np
@@ -42,8 +42,9 @@ def readings(cfg: dict, traffic: dict, seed: int, requests) -> dict:
                                  reference_length, token_gaps)
     tokens, positions = pack_sequences(requests, MAX_SAMPLE,
                                        reference_length(traffic))
-    ref = Reference(cfg, seed).logits_at(tokens, positions)
-    ctl = Reference(cfg, seed, CONTROL_DTYPE).logits_at(tokens, positions)
+    ref = Reference(cfg, fam, seed).logits_at(tokens, positions)
+    ctl = Reference(cfg, fam, seed, CONTROL_DTYPE).logits_at(tokens,
+                                                            positions)
     program = np.concatenate([token_gaps(r, s)
                               for r, (_, s) in zip(ref, requests)])
     control = np.concatenate([token_gaps(r, np.argmax(c, axis=1))
@@ -71,7 +72,7 @@ def main() -> int:
     harness.cache_everything()
     rows = []
     for seed in args.seeds:
-        engine = harness.build_engine(cell.config, seed)
+        engine = harness.build_engine(cell.config, cell.family, seed)
         harness.warm(engine, cell.config)
         client = harness.serve(engine, cell.traffic, cell.config, seed,
                                args.seconds)
@@ -80,7 +81,8 @@ def main() -> int:
         gc.collect()
         row = {"seed": seed, "requests": len(requests),
                "served": sum(len(s) for _, s in requests), "short": short,
-               **readings(cell.config, cell.traffic, seed, requests)}
+               **readings(cell.config, cell.family, cell.traffic, seed,
+                          requests)}
         rows.append(row)
         print(json.dumps(row), flush=True)
     print(json.dumps({

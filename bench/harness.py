@@ -1,8 +1,11 @@
 """One run of one benchmark cell through `repro.serving.ServingEngine`.
 
-Set-up loads the program, makes the configuration's weights on the device
-from the seed in one jitted call (f32 values, then the program's own
-packing to its weight plane), builds the execution plan and the engine,
+The model comes from the configuration's family module (`bench/families/`,
+found by `bench.spec.family`); nothing here names a model.  Set-up loads
+the program, makes the configuration's weights on the device from the
+seed through the family's `program_params` (f32 values, then the
+program's own packing to its weight plane and each path's preparation;
+one jitted call for RWKV-4), builds the execution plan and the engine,
 and warms exactly the cell's two programs: the prefill at
 (max_batch, prefill_chunk) and the decode at (max_batch, 1).  The window
 then drives the cell's traffic (`traffic.py`) for `seconds`, timing every
@@ -12,9 +15,9 @@ be built: any of these fails the run.
 
 After the window, with the peak memory read and the engine freed, a
 sample of the finished requests goes through the plain reference
-(`reference.py`), and the run is correct when no served token's
-reference logit lies further below the reference's best than the
-configuration's limit.
+(`reference.py`, with the family's block), and the run is correct when
+no served token's reference logit lies further below the reference's
+best than the configuration's limit.
 
 With `trace` the window runs under the profiler and the run reports the
 cell's per-layer metrics instead of its end-to-end ones.
@@ -32,6 +35,7 @@ import numpy as np
 
 from bench import spec as specs
 from bench import stats, work
+from bench.spec import RunFailed
 from bench.trace import WINDOW_SPAN
 from bench.traffic import Mix
 
@@ -42,11 +46,6 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # them (its rows are padded to this many whatever the sample holds)
 MAX_SAMPLE = 8
 PREROLL_LIMIT_S = 120.0
-
-
-class RunFailed(RuntimeError):
-    """The run cannot give a result (no chip, a compile in the window, a
-    demoted path, ...)."""
 
 
 def log(msg: str):
@@ -82,43 +81,42 @@ def cache_everything() -> str:
 # ---------------------------------------------------------------------------
 
 
-def build_engine(cfg: dict, seed: int):
-    """The engine the configuration describes, with weights from `seed`."""
+def build_engine(cfg: dict, fam, seed: int):
+    """The engine the configuration describes, with weights from `seed`
+    made by its family module `fam`."""
     import jax
     import jax.numpy as jnp
     from repro.configs.base import ModelConfig
     from repro.core.quant.serving import PreparedParams, pack_params
     from repro.models.registry import get_model
     from repro.serving import ExecutionPlan, ServingEngine
-    from bench import weights
+    from bench.weights import root_key
 
     mcfg, eng = cfg["model"], cfg["engine"]
     model = get_model(ModelConfig(**mcfg))
     if cfg["plane"]["matmul"] != "delta_pot_w8":
         raise RunFailed(f"unknown weight plane {cfg['plane']}")
-    made = jax.eval_shape(lambda: weights.model_params(
-        mcfg, weights.root_key(0)))
+    made = jax.eval_shape(lambda: fam.model_params(mcfg, root_key(0)))
     want = model.abstract_params()
     if (jax.tree_util.tree_structure(made)
             != jax.tree_util.tree_structure(want)
             or [a.shape for a in jax.tree_util.tree_leaves(made)]
             != [a.shape for a in jax.tree_util.tree_leaves(want)]):
         raise RunFailed("the program's parameter tree no longer matches "
-                        "bench/weights.py")
+                        f"{fam.__file__}")
     decode = model.decode_paths()[cfg["paths"]["decode"]]
     prefill = model.prefill_paths()[cfg["paths"]["prefill"]]
 
-    def make(root):
-        # one program: f32 weights from the seed, the program's packing,
-        # and each path's one-time preparation (None: the packed tree).
-        # The key is an argument, so every seed runs the same program.
-        raw = pack_params(weights.model_params(mcfg, root))
+    def pack(tree):
+        # the program's packing, and each path's one-time preparation
+        # (None: the packed tree)
+        raw = pack_params(tree)
         return raw, *(None if d.prepare is None
                       else model.prepare_path_params(d, raw)
                       for d in (decode, prefill))
 
     raw, dec, pre = jax.block_until_ready(
-        jax.jit(make)(weights.root_key(seed)))
+        fam.program_params(cfg, root_key(seed), pack))
     prepared = PreparedParams(
         raw=raw, decode=raw if dec is None else dec,
         prefill=raw if pre is None else pre, quantized=True,
@@ -332,7 +330,7 @@ def sampled_requests(client: Client, seed: int):
             for r in picked], short
 
 
-def logit_gaps(cfg: dict, traffic: dict, seed: int, requests) -> dict:
+def logit_gaps(cfg: dict, fam, traffic: dict, seed: int, requests) -> dict:
     """How far each served token's reference logit lies below the
     reference's best logit at its position: the widest and the mean gap
     over every served token of the sample."""
@@ -340,7 +338,7 @@ def logit_gaps(cfg: dict, traffic: dict, seed: int, requests) -> dict:
                                  reference_length, token_gaps)
     tokens, positions = pack_sequences(requests, MAX_SAMPLE,
                                        reference_length(traffic))
-    logits = Reference(cfg, seed).logits_at(tokens, positions)
+    logits = Reference(cfg, fam, seed).logits_at(tokens, positions)
     gaps = np.concatenate([token_gaps(lg, served)
                            for lg, (_, served) in zip(logits, requests)])
     return {"max_logit_gap": float(gaps.max()),
@@ -382,6 +380,7 @@ def serve(engine, traffic: dict, cfg: dict, seed: int, seconds: float,
 class Run:
     """What the per-layer readers see of a finished run."""
     config: dict
+    family: object
     peak: dict
     counts: dict
     trace: object               # trace.Summary or None
@@ -403,7 +402,7 @@ def run_cell(cell: specs.Cell, seed: int, seconds: float, trace: bool, *,
 
     span = jax.profiler.TraceAnnotation if trace else no_span
     log(f"set-up: imports done at {time.perf_counter() - t_start:.3f} s")
-    engine = build_engine(cfg, seed)
+    engine = build_engine(cfg, cell.family, seed)
     log(f"set-up: engine built at {time.perf_counter() - t_start:.3f} s")
     if fault is not None:
         fault(engine)
@@ -486,7 +485,7 @@ def run_cell(cell: specs.Cell, seed: int, seconds: float, trace: bool, *,
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
 
-    run = Run(cfg, peak, counts, summary)
+    run = Run(cfg, cell.family, peak, counts, summary)
     if trace:
         metrics = {}
         for m in cell.per_layer:
@@ -509,8 +508,8 @@ def run_cell(cell: specs.Cell, seed: int, seconds: float, trace: bool, *,
     gc.collect()
 
     t_ref = time.perf_counter()
-    gaps = logit_gaps(cfg, cell.traffic, seed, requests) if requests \
-        else {}
+    gaps = (logit_gaps(cfg, cell.family, cell.traffic, seed, requests)
+            if requests else {})
     log(f"reference over {len(requests)} requests, "
         f"{sum(len(s) for _, s in requests)} served tokens, in "
         f"{time.perf_counter() - t_ref:.3f} s")

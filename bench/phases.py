@@ -178,7 +178,7 @@ def run(cell, seed: int, seconds: float) -> dict:
                 f"{len(devs)} x {devs[0].device_kind}, cache "
                 f"{harness.cache_everything()}")
     cfg = cell.config
-    engine = harness.build_engine(cfg, seed)
+    engine = harness.build_engine(cfg, cell.family, seed)
     probe = harness.Probe(engine.scheduler, TraceAnnotation)
     harness.warm(engine, cfg)
     counters = engine.counters

@@ -37,8 +37,25 @@ def decode_hbm_roofline(run):
     active = c["decode_lanes"] / c["decode_calls"]
     model = run.config["model"]
     least = work.roofline_seconds(
-        work.token_flops(model, active, active),
-        work.decode_step_bytes(run.config, active), run.peak)
+        work.token_flops(run.family, model, active, active),
+        work.decode_step_bytes(run.family, run.config, active), run.peak)
+    return 100.0 * least / per_call
+
+
+def prefill_roofline(run):
+    """The prefill program's share of its roofline, in %: the least time a
+    call over the window's mean valid prompt tokens could take (their
+    FLOPs through the blocks, the head left out; every stored weight, the
+    pool's state read and written, the tokens' embedding rows), over the
+    program's mean device time per call.  Padded positions are waste."""
+    per_call = _program_call_s(run, "prefill")
+    c = run.counts
+    if per_call is None or run.peak is None or not c["prefill_calls"]:
+        return None
+    tokens = c["prefill_tokens"] / c["prefill_calls"]
+    least = work.roofline_seconds(
+        work.token_flops(run.family, run.config["model"], tokens, 0),
+        work.prefill_call_bytes(run.family, run.config, tokens), run.peak)
     return 100.0 * least / per_call
 
 
@@ -48,7 +65,7 @@ def mfu(run):
     c = run.counts
     if run.peak is None or not c["tick_wall_s"]:
         return None
-    flops = work.token_flops(run.config["model"],
+    flops = work.token_flops(run.family, run.config["model"],
                              c["prefill_tokens"] + c["decode_lanes"],
                              c["decode_lanes"] + c["first_tokens"])
     return 100.0 * flops / (run.peak["bf16_flops_per_s"] * c["tick_wall_s"])

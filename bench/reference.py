@@ -1,8 +1,11 @@
-"""Plain float32 RWKV-4 reference: weights from the seed, the stated plane
-applied by its definition, one layer at a time.
+"""Plain float32 reference: weights from the seed, the stated plane applied
+by its definition, one layer at a time.
 
-It imports nothing of the program.  Its weights come from `weights.py`
-and the configuration's weight plane is read from its definition:
+It imports nothing of the program.  The model comes from the
+configuration's family module (`bench/families/`, see
+`bench.spec.family`): its weights, made one layer at a time, and its
+block's forward pass.  What stays here is what every family shares: the
+configuration's weight plane, read from its definition,
 
   * Δ-PoT W8 (the paper's §3.1 with term widths (3, 4) and a sign bit):
     a level is 0, 2^-a, or 2^-a + 2^-(a+b) for a in 1..7, b in 1..15;
@@ -11,17 +14,13 @@ and the configuration's weight plane is read from its definition:
     (the plane's scale is per column of the stacked leaf), and a weight
     becomes sign(w) * scale * the level nearest |w| / scale (ties to
     the lower level);
-  * every other parameter is stored in the plane's `other` dtype.
+  * every other parameter is stored in the plane's `other` dtype;
 
-The forward pass follows BlinkDL's RWKV-4: ln0 after the embedding; per
-block a LayerNorm, the time mix (token shift of the normalised input,
-receptance/key/value projections, the WKV recurrence with decay
-exp(time_decay) and bonus time_first in its running-max form, the
-sigmoid(r) gate, the output projection) and the channel mix (token shift,
-sigmoid receptance, squared-ReLU key, value); ln_f and the head.  Every
-matmul runs in float32 at `highest` precision.  The control rounds, one
-precision step below the configuration's, every value that the served
-program rounds to its compute or state dtype.
+and the pass around the blocks: ln0 after the embedding, the family's
+blocks in order, ln_f and the head.  Every matmul runs in float32 at
+`highest` precision.  The control rounds, one precision step below the
+configuration's, every value that the served program rounds to its
+compute or state dtype.
 """
 from __future__ import annotations
 
@@ -31,7 +30,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bench import weights as W
+from bench.weights import root_key
 
 W8_KS = (3, 4)
 
@@ -69,7 +68,8 @@ def w8_scale(amax):
     return jnp.where(s <= 0, 1.0, s)
 
 
-def _stored(x, dtype):
+def stored(x, dtype):
+    """x as a leaf stored in `dtype` reads back (f32)."""
     return x.astype(dtype).astype(jnp.float32)
 
 
@@ -79,81 +79,33 @@ def layer_norm(x, p, eps=1e-5):
     return (x - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
 
 
-def _shift(h):
-    """(B, T, D) -> the previous position's value, zero before the first."""
-    return jnp.concatenate([jnp.zeros_like(h[:, :1]), h[:, :-1]], axis=1)
-
-
-def wkv(k, v, w, u, rnd):
-    """RWKV-4 WKV over (B, T, D), running-max form, from a zero state,
-    the state passed through `rnd` after every step."""
-    def step(carry, kv):
-        a, b, o = carry
-        kt, vt = kv
-        no = jnp.maximum(o, u + kt)
-        e1, e2 = jnp.exp(o - no), jnp.exp(u + kt - no)
-        out = (e1 * a + e2 * vt) / (e1 * b + e2)
-        no2 = jnp.maximum(o - w, kt)
-        e1, e2 = jnp.exp(o - w - no2), jnp.exp(kt - no2)
-        return (rnd(e1 * a + e2 * vt), rnd(e1 * b + e2), rnd(no2)), out
-
-    zero = jnp.zeros_like(k[:, 0])
-    init = (zero, zero, rnd(jnp.full_like(zero, -1e38)))
-    _, out = jax.lax.scan(step, init, (jnp.swapaxes(k, 0, 1),
-                                      jnp.swapaxes(v, 0, 1)))
-    return jnp.swapaxes(out, 0, 1)
-
-
-def _plane_layer(cfg, root, layer, amax):
+def _plane_layer(cfg, fam, root, layer, amax):
     """Layer `layer` as the configuration's plane stores it (f32)."""
     model, other = cfg["model"], cfg["plane"]["other"]
-    raw = W.layer_params(model, root, layer)
-    p = jax.tree_util.tree_map(lambda x: _stored(x, other), raw)
-    for a, b in W.MATMUL_LEAVES:
+    raw = fam.layer_params(model, root, layer)
+    p = jax.tree_util.tree_map(lambda x: stored(x, other), raw)
+    for a, b in fam.MATMUL_LEAVES:
         p[a][b] = w8_value(raw[a][b], w8_scale(amax[(a, b)]))
     return p
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def _leaf_amax(model_items, root, layer):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _leaf_amax(fam, model_items, root, layer):
     model = dict(model_items)
-    p = W.layer_params(model, root, layer)
+    p = fam.layer_params(model, root, layer)
     return {name: jnp.max(jnp.abs(p[name[0]][name[1]]), axis=0)
-            for name in W.MATMUL_LEAVES}
+            for name in fam.MATMUL_LEAVES}
 
 
-def matmul_amax(model: dict, root) -> dict:
+def matmul_amax(fam, model: dict, root) -> dict:
     """Each matmul leaf's per-column max |w| over all layers."""
     items = tuple(sorted(model.items()))
     out = None
     for layer in range(model["n_layers"]):
-        cur = _leaf_amax(items, root, layer)
+        cur = _leaf_amax(fam, items, root, layer)
         out = cur if out is None else {k: jnp.maximum(out[k], cur[k])
                                         for k in out}
     return out
-
-
-def _block(p, x, rnd):
-    """One block.  `rnd` stands where the served program rounds to its
-    compute or state dtype: identity in the reference."""
-    mm = lambda a, b: jnp.matmul(rnd(a), rnd(b),
-                                 precision=jax.lax.Precision.HIGHEST)
-    h = rnd(layer_norm(x, p["ln1"]))
-    a = p["att"]
-    hx = _shift(h)
-    mix = lambda m: rnd(h * a[m] + hx * (1.0 - a[m]))
-    r = rnd(mm(mix("time_mix_r"), a["wr"]))
-    k = rnd(mm(mix("time_mix_k"), a["wk"]))
-    v = rnd(mm(mix("time_mix_v"), a["wv"]))
-    out = rnd(wkv(k, v, jnp.exp(a["time_decay"]), a["time_first"], rnd))
-    x = rnd(x + rnd(mm(rnd(jax.nn.sigmoid(r) * out), a["wo"])))
-    h = rnd(layer_norm(x, p["ln2"]))
-    f = p["ffn"]
-    hx = _shift(h)
-    mix = lambda m: rnd(h * f[m] + hx * (1.0 - f[m]))
-    rr = rnd(jax.nn.sigmoid(rnd(mm(mix("time_mix_r"), f["wr"]))))
-    kk = rnd(jnp.square(jax.nn.relu(rnd(mm(mix("time_mix_k"), f["wk"])))))
-    return rnd(x + rnd(rr * rnd(mm(kk, f["wv"]))))
 
 
 def lower_precision(dtype):
@@ -165,40 +117,32 @@ def lower_precision(dtype):
 
 
 class Reference:
-    """The reference for one configuration and seed: float32 at `highest`.
-    With `control_dtype` every value the served program rounds to its
-    compute or state dtype is rounded through that dtype instead: the
-    control, the program one precision step lower."""
+    """The reference for one configuration, its family module `fam` and a
+    seed: float32 at `highest`.  With `control_dtype` every value the
+    served program rounds to its compute or state dtype is rounded
+    through that dtype instead: the control, the program one precision
+    step lower."""
 
-    def __init__(self, cfg: dict, seed: int, control_dtype=None):
-        self.cfg = cfg
+    def __init__(self, cfg: dict, fam, seed: int, control_dtype=None):
+        self.cfg, self.fam = cfg, fam
         self.model = cfg["model"]
-        self.root = W.root_key(seed)    # an argument of every program
+        self.root = root_key(seed)      # an argument of every program
         self.rnd = (lambda x: x) if control_dtype is None \
             else lower_precision(control_dtype)
         self._embed = jax.jit(self._embed_fn)
         self._layer = jax.jit(self._layer_fn)
         self._head = jax.jit(self._head_fn)
 
-    def _top(self, root):
-        """Embedding, outer LayerNorms and head as the plane stores them."""
-        other = self.cfg["plane"]["other"]
-        t = W.top_params(self.model, root)
-        out = jax.tree_util.tree_map(lambda x: _stored(x, other), t)
-        head = t["head"]
-        out["head"] = w8_value(head, w8_scale(jnp.max(jnp.abs(head), 0)))
-        return out
-
     def _embed_fn(self, tokens, root):
-        top = self._top(root)
+        top = self.fam.top_stored(self.cfg, root)
         return self.rnd(layer_norm(top["embed"][tokens], top["ln0"]))
 
     def _layer_fn(self, x, layer, amax, root):
-        return _block(_plane_layer(self.cfg, root, layer, amax), x,
-                      self.rnd)
+        return self.fam.block(
+            _plane_layer(self.cfg, self.fam, root, layer, amax), x, self.rnd)
 
     def _head_fn(self, rows, root):
-        top = self._top(root)
+        top = self.fam.top_stored(self.cfg, root)
         return jnp.matmul(self.rnd(layer_norm(rows, top["ln_f"])),
                           self.rnd(top["head"]),
                           precision=jax.lax.Precision.HIGHEST)
@@ -207,7 +151,7 @@ class Reference:
                   block_rows: int = 1024) -> list[np.ndarray]:
         """f32 logits of sequences `tokens` (B, T), padded at the end, at
         the given positions of each row: a list of (n_b, V) arrays."""
-        amax = matmul_amax(self.model, self.root)
+        amax = matmul_amax(self.fam, self.model, self.root)
         x = self._embed(jnp.asarray(tokens), self.root)
         for layer in range(self.model["n_layers"]):
             x = self._layer(x, jnp.int32(layer), amax, self.root)

@@ -35,7 +35,7 @@ def main() -> int:
     cell = spec.load_cell(args.workload, ROOT)
     harness.check_devices(cell.chips)
     harness.cache_everything()
-    engine = harness.build_engine(cell.config, args.seed)
+    engine = harness.build_engine(cell.config, cell.family, args.seed)
     harness.warm(engine, cell.config)
     for rate in args.rates:
         client = harness.serve(engine, {**cell.traffic, "rate_per_s": rate},

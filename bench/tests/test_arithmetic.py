@@ -5,13 +5,17 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from bench import harness, stats, work
+from bench import harness, readers, spec, stats, work
 from bench.traffic import Mix, quantile_lengths
 from conftest import ROOT
 
 
 def config(name):
     return json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
+
+
+def family(name):
+    return spec.family(config(name)["model"])
 
 
 # hand counts: per block 5 D^2 + 2 D F matmul weights, head D V
@@ -25,15 +29,16 @@ HAND = {
 
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_weights_and_flops(name):
-    model = config(name)["model"]
+    model, fam = config(name)["model"], family(name)
     hand = HAND[name]
-    assert work.layer_matmul_weights(model) == hand["layer"]
-    assert work.head_weights(model) == hand["head"]
-    assert work.matmul_weights(model) == hand["matmul"]
+    assert fam.layer_matmul_weights(model) == hand["layer"]
+    assert fam.head_weights(model) == hand["head"]
+    assert work.matmul_weights(fam, model) == hand["matmul"]
     # a decode token: every matmul weight twice, head included
-    assert work.token_flops(model, 1, 1) == 2 * hand["matmul"]
+    assert work.token_flops(fam, model, 1, 1) == 2 * hand["matmul"]
     # a prompt token that produces no logits skips the head
-    assert work.token_flops(model, 1, 0) == 2 * (hand["matmul"] - hand["head"])
+    assert work.token_flops(fam, model, 1, 0) == \
+        2 * (hand["matmul"] - hand["head"])
 
 
 def test_decode_step_bytes_169m():
@@ -41,8 +46,9 @@ def test_decode_step_bytes_169m():
     D, F, V, L = 768, 3072, 50277, 12
     weights = 130_625_280 + 4 * (5 * D + F + V) + 2 * (11 * D * L + 4 * D)
     lane = 2 * D + 2 * (2 * L * 5 * D) + 2 * V
-    assert work.decode_step_bytes(cfg, 0) == weights == 131_062_932
-    assert work.decode_step_bytes(cfg, 32) == weights + 32 * lane \
+    fam = family("rwkv4-169m-w8")
+    assert work.decode_step_bytes(fam, cfg, 0) == weights == 131_062_932
+    assert work.decode_step_bytes(fam, cfg, 32) == weights + 32 * lane \
         == 140_228_052
 
 
@@ -51,7 +57,39 @@ def test_decode_step_bytes_1b5():
     D, F, V, L = 2048, 8192, 50277, 24
     weights = 1_411_590_144 + 4 * (5 * D + F + V) + 2 * (11 * D * L + 4 * D)
     lane = 2 * D + 2 * (2 * L * 5 * D) + 2 * V
-    assert work.decode_step_bytes(cfg, 32) == weights + 32 * lane
+    fam = family("rwkv4-1b5-w8")
+    assert work.decode_step_bytes(fam, cfg, 32) == weights + 32 * lane
+    assert work.decode_step_bytes(fam, cfg, 32) == 1_447_768_788
+
+
+def test_prefill_call_bytes_1b5():
+    """Every stored weight, 32 lanes' state read and written, and the
+    absorbed tokens' embedding rows, all by hand."""
+    cfg = config("rwkv4-1b5-w8")
+    D, F, V, L = 2048, 8192, 50277, 24
+    weights = 1_411_590_144 + 4 * (5 * D + F + V) + 2 * (11 * D * L + 4 * D)
+    fam = family("rwkv4-1b5-w8")
+    assert work.weight_bytes(fam, cfg) == weights
+    assert work.prefill_call_bytes(fam, cfg, 1000) == \
+        weights + 32 * 2 * (2 * L * 5 * D) + 1000 * 2 * D
+
+
+def test_prefill_roofline_by_hand():
+    """1,024 valid tokens a call at 1.5B: FLOPs-bound, 2 x 1,024 x the
+    blocks' matmul weights at 197 TFLOP/s, over a 40 ms call."""
+    cfg = config("rwkv4-1b5-w8")
+    peak = work.peaks("TPU v5 lite")
+    trace = SimpleNamespace(modules={"prefill": (10, 0.4)})
+    run = harness.Run(cfg, family("rwkv4-1b5-w8"), peak,
+                      {"prefill_calls": 4, "prefill_tokens": 4096}, trace)
+    least = 2 * 1024 * 24 * 54_525_952 / 197e12
+    assert readers.prefill_roofline(run) == pytest.approx(
+        100 * least / 0.04, rel=1e-12)
+    assert readers.prefill_roofline(
+        harness.Run(cfg, run.family, peak, run.counts, None)) is None
+    assert readers.prefill_roofline(harness.Run(
+        cfg, run.family, peak, {"prefill_calls": 0, "prefill_tokens": 0},
+        trace)) is None
 
 
 def test_peaks_table():
@@ -60,10 +98,10 @@ def test_peaks_table():
     with pytest.raises(KeyError):
         work.peaks("cpu")
     # 1.41 GB at 819 GB/s bounds a 1.5B decode step, not its FLOPs
-    cfg = config("rwkv4-1b5-w8")
-    least = work.roofline_seconds(work.token_flops(cfg["model"], 32, 32),
-                                  work.decode_step_bytes(cfg, 32), peak)
-    assert least == pytest.approx(work.decode_step_bytes(cfg, 32) / 819e9)
+    cfg, fam = config("rwkv4-1b5-w8"), family("rwkv4-1b5-w8")
+    least = work.roofline_seconds(work.token_flops(fam, cfg["model"], 32, 32),
+                                  work.decode_step_bytes(fam, cfg, 32), peak)
+    assert least == pytest.approx(work.decode_step_bytes(fam, cfg, 32) / 819e9)
 
 
 def test_nearest_rank():
@@ -115,7 +153,7 @@ def test_quantile_lengths():
     assert sorted(uni) == list(range(16, 65))
 
 
-@pytest.mark.parametrize("traffic", ["chat", "gen"])
+@pytest.mark.parametrize("traffic", ["chat", "gen", "doc"])
 def test_traffic_is_a_function_of_the_seed(traffic):
     t = json.loads((ROOT / "bench" / "traffic" / f"{traffic}.json")
                    .read_text())

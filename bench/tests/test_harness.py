@@ -16,7 +16,7 @@ import time
 import numpy as np
 import pytest
 
-from bench import harness, spec
+from bench import harness, spec, weights, work
 from conftest import FIXTURE, ROOT
 
 CELL = "tiny-w8.trickle"
@@ -28,11 +28,88 @@ def test_lookup_is_data_driven():
     assert cell.traffic["loop"] == "open"
     assert [m["name"] for m in cell.end_to_end] == ["ttft_p95_ms", "setup_s"]
     assert list(cell.readers) == ["decode_lanes.trickle"]
-    run = harness.Run(cell.config, None,
+    run = harness.Run(cell.config, cell.family, None,
                       {"decode_calls": 10, "decode_lanes": 25}, None)
     assert cell.readers["decode_lanes.trickle"](run) == 2.5
     with pytest.raises(KeyError):
         spec.load_cell("no-such.cell", FIXTURE)
+
+
+# A family that only this test defines: one D x D matmul per block.
+TOY_FAMILY = '''
+import jax
+import jax.numpy as jnp
+from bench.weights import nest
+
+MATMUL_LEAVES = (("mix", "w"),)
+
+
+def layer_params(model, root, layer):
+    d = model["d_model"]
+    key = jax.random.fold_in(jax.random.fold_in(root, 0), layer)
+    return nest([(("mix", "w"), jax.random.normal(key, (d, d)))])
+
+
+def top_params(model, root):
+    return {"embed": jnp.zeros((model["vocab"], model["d_model"]))}
+
+
+def model_params(model, root):
+    blocks = jax.vmap(lambda l: layer_params(model, root, l))(
+        jnp.arange(model["n_layers"]))
+    return {**top_params(model, root), "blocks": blocks}
+
+
+def layer_matmul_weights(model):
+    return model["d_model"] ** 2
+
+
+def head_weights(model):
+    return 0
+'''
+
+
+def _fixture_copy(tmp_path, family: dict):
+    """The fixture with one more configuration, of model `family`, and
+    one more cell on it; no file of the fixture is edited."""
+    shutil.copytree(FIXTURE, tmp_path, dirs_exist_ok=True)
+    tiny = json.loads((FIXTURE / "bench/configs/tiny-w8.json").read_text())
+    conf = {**tiny, "name": "toy-w8",
+            "model": {**tiny["model"], **family}}
+    (tmp_path / "bench/configs/toy-w8.json").write_text(json.dumps(conf))
+    bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    bench["configs"].append({**bench["configs"][0], "name": "toy-w8",
+                             "file": "bench/configs/toy-w8.json"})
+    bench["workloads"].append({**bench["workloads"][0],
+                               "name": "toy-w8.trickle", "config": "toy-w8"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return conf["model"]
+
+
+def test_a_new_family_is_new_files_only(tmp_path):
+    """A family module dropped into a benchmark's `families/` is found
+    from the configuration that names it, builds its tree and gives the
+    work counts; the cells of the families the benchmark brings resolve
+    beside it."""
+    model = _fixture_copy(tmp_path, {"family": "toy"})
+    (tmp_path / "bench/families").mkdir()
+    (tmp_path / "bench/families/toy.py").write_text(TOY_FAMILY)
+    cell = spec.load_cell("toy-w8.trickle", tmp_path)
+    assert cell.family.__file__ == str(tmp_path / "bench/families/toy.py")
+    tree = cell.family.model_params(model, weights.root_key(1))
+    assert tree["blocks"]["mix"]["w"].shape == (2, 64, 64)
+    assert work.token_flops(cell.family, model, 10, 3) == 2 * 10 * 2 * 64**2
+    tiny = spec.load_cell(CELL, tmp_path).family
+    assert tiny is spec.load_cell(CELL, FIXTURE).family
+    assert tiny.__file__ == str(ROOT / "bench/families/rwkv4.py")
+
+
+def test_a_family_without_a_module_fails(tmp_path):
+    _fixture_copy(tmp_path, {"family": "rwkv", "rwkv_version": 99})
+    with pytest.raises(spec.RunFailed) as e:
+        spec.load_cell("toy-w8.trickle", tmp_path)
+    assert str(tmp_path / "bench/families/rwkv99.py") in str(e.value)
+    assert harness.RunFailed is spec.RunFailed
 
 
 def test_every_cell_of_the_benchmark_resolves():
@@ -44,6 +121,32 @@ def test_every_cell_of_the_benchmark_resolves():
         assert cell.per_layer
         assert all(m["moves"] in names for m in cell.per_layer)
         assert set(cell.readers) == {m["name"] for m in cell.per_layer}
+
+
+def test_every_reader_reads_a_run():
+    """Each per-layer metric of each cell reads a run that holds
+    something for it (a made-up window on a described v5e), and reads
+    nothing from a run without a trace where it needs one."""
+    from types import SimpleNamespace
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    trace = SimpleNamespace(modules={"decode": (100, 1.0),
+                                     "prefill": (10, 0.6)},
+                            idle_share=0.25)
+    counts = dict(decode_calls=100, decode_lanes=3000, prefill_calls=10,
+                  prefill_tokens=15000, first_tokens=40, tick_wall_s=2.0)
+    for w in bench["workloads"]:
+        cell = spec.load_cell(w["name"], ROOT)
+        run = harness.Run(cell.config, cell.family,
+                          work.peaks("TPU v5 lite"), counts, trace)
+        for m in cell.per_layer:
+            value = cell.readers[m["name"]](run)
+            assert isinstance(value, float) and value > 0, m["name"]
+            if m["unit"] == "%":
+                assert value <= 100.0, (m["name"], value)
+            if m["source"] == "device_trace":
+                assert cell.readers[m["name"]](harness.Run(
+                    cell.config, cell.family, run.peak, counts,
+                    None)) is None, m["name"]
 
 
 def _run(args, cwd, **env):
@@ -85,6 +188,15 @@ def state_unchanged(engine, _):
     _decode_mask(engine, np.zeros_like)
 
 
+def prefill_unchanged(engine, _):
+    """A prefill step that returns its state unchanged: no prompt token
+    of any lane is absorbed."""
+    sched = engine.scheduler
+    prefill = sched.prefill_fn
+    sched.prefill_fn = lambda state, toks, valid, fresh: prefill(
+        state, toks, np.zeros_like(valid), fresh)
+
+
 def half_batch(engine, _):
     """Half of the batch left out: of each decode call's active lanes, the
     later half (a lone lane included) never commits its step."""
@@ -105,10 +217,10 @@ def token_altered(engine, monkeypatch):
                         lambda rows, metas: (sample(rows, metas) + 1) % vocab)
 
 
-@pytest.mark.parametrize("fault", [None, state_unchanged, half_batch,
-                                   token_altered],
-                         ids=["sound", "state_unchanged", "half_batch",
-                              "token_altered"])
+@pytest.mark.parametrize("fault", [None, state_unchanged, prefill_unchanged,
+                                   half_batch, token_altered],
+                         ids=["sound", "state_unchanged", "prefill_unchanged",
+                              "half_batch", "token_altered"])
 def test_correct_catches_faults(fault, monkeypatch):
     cell = spec.load_cell(CELL, FIXTURE)
     out = harness.run_cell(

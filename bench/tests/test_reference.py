@@ -1,11 +1,18 @@
-"""The reference's reading of the W8 plane and its weights."""
+"""The reference's reading of the W8 plane, its weights and its logits."""
+import hashlib
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import weights
-from bench.reference import MAX_LEVEL, w8_level, w8_scale, w8_value
+from bench import spec, weights
+from bench.reference import (MAX_LEVEL, Reference, w8_level, w8_scale,
+                             w8_value)
+from conftest import FIXTURE, ROOT
+
+SEED = 3_000_000_007
 
 
 def brute_levels():
@@ -56,10 +63,12 @@ def test_weights_layer_by_layer():
     programs may evaluate the normal draws' inverse error function with
     different vector code, a few ulp apart (far below the bf16 the
     program stores)."""
-    model = dict(n_layers=3, d_model=8, d_ff=32, vocab=16)
-    root = weights.root_key(3_000_000_007)
-    stacked = weights.model_params(model, root)
-    one_layer = jax.jit(lambda l: weights.layer_params(model, root, l))
+    model = dict(family="rwkv", rwkv_version=4, n_layers=3, d_model=8,
+                 d_ff=32, vocab=16)
+    fam = spec.family(model)
+    root = weights.root_key(SEED)
+    stacked = fam.model_params(model, root)
+    one_layer = jax.jit(lambda l: fam.layer_params(model, root, l))
     for layer in range(3):
         one = one_layer(jnp.int32(layer))
         flat_s = jax.tree_util.tree_leaves(stacked["blocks"])
@@ -70,3 +79,56 @@ def test_weights_layer_by_layer():
     assert weights.root_key(5).shape == weights.root_key(2 ** 40 + 5).shape
     assert not np.array_equal(weights.root_key(5),
                               weights.root_key(2 ** 40 + 5))
+
+
+def digest(tree) -> str:
+    h = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(tree):
+        a = np.asarray(leaf)
+        h.update(str(a.dtype).encode())
+        h.update(str(a.shape).encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()[:16]
+
+
+def _config(path):
+    return json.loads(path.read_text())
+
+
+# Digests of what the benchmark made before its model moved into
+# `bench/families/rwkv4.py` (the same programs on the same seed, on the
+# CPU): the move changed no weight, no W8 code and no reference logit.
+PINNED = {"model_params": "fa4cf39a6bb2f123", "codes": "b3dc8cb77cc6da0d",
+          "layer0_169m": "e1087e8dc60bbd74", "top_169m": "a7c9d8a577afdaa6",
+          "logits": "dec9d87f7d7da4ea", "control": "ee47de1db0cade58"}
+
+
+def test_weights_and_codes_are_pinned():
+    from repro.core.quant.serving import pack_params
+    tiny = _config(FIXTURE / "bench" / "configs" / "tiny-w8.json")
+    m169 = _config(ROOT / "bench" / "configs" / "rwkv4-169m-w8.json")["model"]
+    fam = spec.family(tiny["model"])
+    assert spec.family(m169) is fam
+    root = weights.root_key(SEED)
+    got = {
+        "model_params": digest(jax.jit(
+            lambda r: fam.model_params(tiny["model"], r))(root)),
+        "codes": digest(fam.program_params(tiny, root, pack_params)),
+        "layer0_169m": digest(jax.jit(
+            lambda l: fam.layer_params(m169, root, l))(jnp.int32(0))),
+        "top_169m": digest(jax.jit(
+            lambda r: fam.top_params(m169, r))(root))}
+    assert got == {k: PINNED[k] for k in got}
+
+
+def test_reference_logits_are_pinned():
+    tiny = _config(FIXTURE / "bench" / "configs" / "tiny-w8.json")
+    fam = spec.family(tiny["model"])
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, tiny["model"]["vocab"], (3, 40)).astype(np.int32)
+    positions = [np.arange(5, 12), np.arange(30, 40), np.arange(0, 3)]
+    got = {"logits": digest(Reference(tiny, fam, SEED).logits_at(
+               tokens, positions)),
+           "control": digest(Reference(tiny, fam, SEED, "float8_e4m3fn")
+                             .logits_at(tokens, positions))}
+    assert got == {k: PINNED[k] for k in got}

@@ -1,13 +1,20 @@
 """The work a step of the model needs, computed from its configuration.
 
-Counts come from the configuration file and its stated weight plane only,
-never from the program's arrays or compiled code, so the same work is
-counted whatever implements it.  A decode step reads every stored weight
-once (W8 codes at one byte each, the per-channel f32 scales, the other
-parameters in their stored dtype), the embedding rows of its active lanes,
-and the recurrent state of its active lanes, which it also writes, and it
-writes one row of logits per active lane.  FLOPs are two per matmul weight
-per token, the head counted only for positions that produce logits.
+The model's own counts (matmul weights per block and in the head, W8
+scales, small parameters, recurrent state per lane) come from its family
+module (`bench/families/`, see `bench.spec.family`); what is built from
+them here is the same for every family.  Counts come from the
+configuration file and its stated weight plane only, never from the
+program's arrays or compiled code, so the same work is counted whatever
+implements it.  A decode step reads every stored weight once (W8 codes at
+one byte each, the per-channel f32 scales, the other parameters in their
+stored dtype), the embedding rows of its active lanes, and the recurrent
+state of its active lanes, which it also writes, and it writes one row of
+logits per active lane.  A prefill call reads every stored weight once,
+the embedding rows of the prompt tokens it absorbs, and the state of
+every lane of the pool, which it also writes.  FLOPs are two per matmul
+weight per token, the head counted only for positions that produce
+logits.
 """
 from __future__ import annotations
 
@@ -22,59 +29,58 @@ def _itemsize(dtype: str) -> int:
     return _BYTES[dtype]
 
 
-def layer_matmul_weights(model: dict) -> int:
-    """Matmul weights of one RWKV-4 block: r, k, v, o of the time mix
-    (D x D each) and the channel mix's r (D x D), k (D x F) and v (F x D)."""
-    d, f = model["d_model"], model["d_ff"]
-    return 5 * d * d + 2 * d * f
+def matmul_weights(fam, model: dict) -> int:
+    """Every matmul weight: the blocks' and the head's."""
+    return (model["n_layers"] * fam.layer_matmul_weights(model)
+            + fam.head_weights(model))
 
 
-def head_weights(model: dict) -> int:
-    return model["d_model"] * model["vocab"]
-
-
-def matmul_weights(model: dict) -> int:
-    return model["n_layers"] * layer_matmul_weights(model) + head_weights(model)
-
-
-def scale_count(model: dict) -> int:
-    """Per-output-channel scales of the W8 plane: one per column of each
-    matmul leaf, shared over the stacked layers (5 D + F), plus the head's V."""
-    d, f = model["d_model"], model["d_ff"]
-    return 5 * d + f + model["vocab"]
-
-
-def small_param_count(model: dict) -> int:
-    """Parameters outside the matmuls that a step reads whole: per block two
-    LayerNorms (2 x 2D), the time mix's three mixes, decay and bonus (5D)
-    and the channel mix's two mixes (2D); ln0 and ln_f (2 x 2D)."""
-    d = model["d_model"]
-    return model["n_layers"] * 11 * d + 4 * d
-
-
-def state_elems_per_lane(model: dict) -> int:
-    """att_x, ffn_x and the WKV a, b, o per layer."""
-    return model["n_layers"] * 5 * model["d_model"]
-
-
-def decode_step_bytes(cfg: dict, active: float) -> float:
-    """Bytes a decode step over `active` lanes must move."""
+def weight_bytes(fam, cfg: dict) -> int:
+    """Every stored weight once: W8 codes at one byte each, the
+    per-channel f32 scales, the other parameters in their stored dtype."""
     model, plane = cfg["model"], cfg["plane"]
     assert plane["matmul"] == "delta_pot_w8", plane
-    other = _itemsize(plane["other"])
-    weights = (matmul_weights(model) * 1 + scale_count(model) * 4
-               + small_param_count(model) * other)
-    lane = (model["d_model"] * other                       # embedding row
-            + 2 * state_elems_per_lane(model) * _itemsize(cfg["engine"]["state_dtype"])
-            + model["vocab"] * _itemsize(model["dtype"]))  # logits row
-    return weights + active * lane
+    return (matmul_weights(fam, model) * 1 + fam.scale_count(model) * 4
+            + fam.small_param_count(model) * _itemsize(plane["other"]))
 
 
-def token_flops(model: dict, tokens: float, logit_positions: float) -> float:
-    """Matmul FLOPs of `tokens` positions through the blocks, of which
-    `logit_positions` also go through the head."""
-    return 2.0 * (tokens * model["n_layers"] * layer_matmul_weights(model)
-                  + logit_positions * head_weights(model))
+def _lane_state_bytes(fam, cfg: dict) -> int:
+    """One lane's recurrent state, read and written."""
+    return 2 * fam.state_elems_per_lane(cfg["model"]) * _itemsize(
+        cfg["engine"]["state_dtype"])
+
+
+def decode_step_bytes(fam, cfg: dict, active: float) -> float:
+    """Bytes a decode step over `active` lanes must move."""
+    model = cfg["model"]
+    lane = (model["d_model"] * _itemsize(cfg["plane"]["other"])  # embedding row
+            + _lane_state_bytes(fam, cfg)
+            + model["vocab"] * _itemsize(model["dtype"]))        # logits row
+    return weight_bytes(fam, cfg) + active * lane
+
+
+def prefill_call_bytes(fam, cfg: dict, tokens: float) -> float:
+    """Bytes a prefill call absorbing `tokens` prompt tokens must move:
+    every stored weight, the state of every lane of the pool, read and
+    written, and the tokens' embedding rows."""
+    model = cfg["model"]
+    return (weight_bytes(fam, cfg)
+            + cfg["engine"]["max_batch"] * _lane_state_bytes(fam, cfg)
+            + tokens * model["d_model"] * _itemsize(cfg["plane"]["other"]))
+
+
+def token_flops(fam, model: dict, tokens: float,
+                logit_positions: float) -> float:
+    """FLOPs of `tokens` positions through the blocks, of which
+    `logit_positions` also go through the head: two per matmul weight,
+    and the family's `layer_extra_flops` per position and layer where it
+    counts work outside the matmuls."""
+    flops = 2.0 * (tokens * model["n_layers"] * fam.layer_matmul_weights(model)
+                   + logit_positions * fam.head_weights(model))
+    extra = getattr(fam, "layer_extra_flops", None)
+    if extra is not None:
+        flops += tokens * model["n_layers"] * extra(model)
+    return flops
 
 
 def peaks(device_kind: str, path: Path | None = None) -> dict:
