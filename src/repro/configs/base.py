@@ -66,6 +66,8 @@ class ModelConfig:
     # --- RWKV / SSM ---
     rwkv_version: int = 0                   # 4 or 6 (0 = not rwkv)
     rwkv_head_dim: int = 64                 # rwkv6 head size
+    rwkv_mix_rank: int = 32                 # rwkv6 ddlerp LoRA rank
+    rwkv_decay_rank: int = 64               # rwkv6 decay LoRA rank
     ssm_state: int = 64                     # mamba2 state dim
     ssm_head_dim: int = 64                  # mamba2 head (value) dim
     ssm_expand: int = 2                     # mamba2 inner = expand*d_model

@@ -16,7 +16,10 @@ Implementation notes
   ks=(3, 4) is the 7-code-bit variant that packs *with* its sign into one
   int8 word for the Pallas serving kernel; ks=(4,) degenerates to plain PoT.
 * Levels are enumerated once per format (≤ 2^8 = 256 entries) and quantization
-  is nearest-level via `searchsorted` on midpoints — exact nearest rounding.
+  is nearest-level, ties to the lower level — exact nearest rounding.  Formats
+  of one or two terms find the code in closed form from the exponent and
+  mantissa (`_closed_form_code`: elementwise, no table lookup, so packing a
+  7B model costs seconds); longer ones search the sorted midpoints.
 * The scale γ is chosen per-channel (`axis` = the *output*-channel axis of a
   weight matrix) so that the maximum representable level hits the channel's
   max |w|; an optional MSE grid-search refines it, matching how the paper
@@ -172,6 +175,13 @@ class DPotQuantized:
         return cls(codes=codes, signs=signs, scale=scale, ks=aux[0])
 
 
+def dpot_channel_scale(amax: jnp.ndarray, fmt: DPotFormat) -> jnp.ndarray:
+    """The scale s = 2γ that maps a channel's max |w| to the largest level
+    (1 for an all-zero channel)."""
+    base = amax / dpot_max_level(fmt)
+    return jnp.where(base <= 0, 1.0, base)
+
+
 def _choose_scale(absw: jnp.ndarray, axis, fmt: DPotFormat,
                   mse_search: bool, x_for_mse: jnp.ndarray | None):
     """Per-channel scale s = 2γ so that s * max_level covers max|w|."""
@@ -183,9 +193,7 @@ def _choose_scale(absw: jnp.ndarray, axis, fmt: DPotFormat,
         reduce_axes = tuple(i for i in range(absw.ndim) if i not in
                             tuple(a % absw.ndim for a in axes))
         amax = jnp.max(absw, axis=reduce_axes, keepdims=True)
-    max_lvl = dpot_max_level(fmt)
-    base = amax / max_lvl
-    base = jnp.where(base <= 0, 1.0, base)
+    base = dpot_channel_scale(amax, fmt)
     if not mse_search:
         return base
     # grid-search a multiplicative refinement of the scale, minimizing MSE
@@ -213,12 +221,57 @@ def _nearest_level(x_abs_scaled: jnp.ndarray, fmt: DPotFormat) -> jnp.ndarray:
     return lv[idx]
 
 
-def _nearest_code(x_abs_scaled: jnp.ndarray, fmt: DPotFormat) -> jnp.ndarray:
+def _searched_code(x_abs_scaled: jnp.ndarray, fmt: DPotFormat) -> jnp.ndarray:
+    """The code of the level nearest |x|/s (ties low) by a search of the
+    sorted midpoints, for any format."""
     sorted_levels, codes, mids = _sorted_levels_np(fmt.ks)
     cd = jnp.asarray(codes, jnp.int32)
     md = jnp.asarray(mids, jnp.float32)
     idx = jnp.searchsorted(md, x_abs_scaled.astype(jnp.float32))
     return cd[idx].astype(jnp.uint8)
+
+
+def _closed_form_code(y: jnp.ndarray, ks: tuple[int, ...]) -> jnp.ndarray:
+    """The code of the level nearest y >= 0 (ties low) for a format of one
+    or two terms, elementwise and exact.
+
+    With A = 2^k0 - 1 and B = 2^k1 - 1 the levels are 0, 2^-a (a in 1..A)
+    and, with a second term, 2^-a (1 + 2^-b) (b in 1..B).  Writing
+    y = 2^-a (1 + t), t in [0, 1) (`frexp`, exact), the nearest level in
+    that octave rounds t to 0, to a power of two 2^-b, or up to 1 (the next
+    octave's 2^-(a-1)); between 2^k and 2^(k+1) the midpoint is
+    1.5 x 2^k.  Every midpoint is exact in f32, so the comparisons decide
+    exactly what a search of the midpoints decides.  Below 2^-A only 0
+    and 2^-A remain; above the largest level, the largest level's code."""
+    k0 = ks[0]
+    A = (1 << k0) - 1
+    B = (1 << ks[1]) - 1 if len(ks) == 2 else 0
+    y = y.astype(jnp.float32)
+    mant, ex = jnp.frexp(y)
+    a = 1 - ex                                  # y in [2^-a, 2^-(a-1))
+    t = 2.0 * mant - 1.0
+    if B:
+        tm, tk = jnp.frexp(t)                   # t in [2^(tk-1), 2^tk)
+        up = tm > 0.75
+        carry = up & (tk == 0)                  # t > 3/4: up to 2^-(a-1)
+        b = jnp.minimum(jnp.where(up, -tk, 1 - tk), B)
+        b = jnp.where(carry | (t <= 2.0 ** -(B + 1)), 0, b)
+        max_level, max_code = 0.75, 1 + (1 << k0)
+    else:
+        carry = t > 0.5
+        b = jnp.zeros_like(a)
+        max_level, max_code = 0.5, 1
+    code = (a - carry.astype(a.dtype)) + (b << k0)
+    code = jnp.where(a > A, A, code)            # (2^-(A+1), 2^-A): 2^-A
+    code = jnp.where(y <= 2.0 ** -(A + 1), 0, code)
+    code = jnp.where(y > max_level, max_code, code)
+    return code.astype(jnp.uint8)
+
+
+def _nearest_code(x_abs_scaled: jnp.ndarray, fmt: DPotFormat) -> jnp.ndarray:
+    if fmt.n_terms <= 2:
+        return _closed_form_code(x_abs_scaled, fmt.ks)
+    return _searched_code(x_abs_scaled, fmt)
 
 
 def dpot_quantize(w: jnp.ndarray, fmt: DPotFormat = FORMAT_W9, *,
@@ -230,9 +283,16 @@ def dpot_quantize(w: jnp.ndarray, fmt: DPotFormat = FORMAT_W9, *,
           (None = a single tensor-wide scale).
     """
     w = jnp.asarray(w, jnp.float32)
-    absw = jnp.abs(w)
-    scale = _choose_scale(absw, axis, fmt, mse_search, w)
-    codes = _nearest_code(absw / scale, fmt)
+    scale = _choose_scale(jnp.abs(w), axis, fmt, mse_search, w)
+    return dpot_quantize_scaled(w, scale, fmt)
+
+
+def dpot_quantize_scaled(w: jnp.ndarray, scale: jnp.ndarray,
+                         fmt: DPotFormat) -> DPotQuantized:
+    """Quantize `w` to Δ-PoT codes with a given (broadcastable) scale: the
+    nearest level of |w| / scale, and the sign."""
+    w = jnp.asarray(w, jnp.float32)
+    codes = _nearest_code(jnp.abs(w) / scale, fmt)
     signs = jnp.where(w < 0, -1, 1).astype(jnp.int8)
     return DPotQuantized(codes=codes, signs=signs, scale=scale, ks=fmt.ks)
 

@@ -28,10 +28,14 @@ from repro.core.quant.uniform import (
     uniform_quantize, uniform_fake_quant, uniform_dequantize,
 )
 
-# path substrings that force the uniform branch even for 2-D tensors
+# path substrings that force the uniform branch even for 2-D tensors (a
+# stacked layer axis makes RWKV-6's token-shift mu `time_maa_x` (L, D) and
+# `time_maa` (L, 5, D) and its bonus u `time_faaaa` (L, H, N) 2-D or more;
+# its LoRA matrices `maa_w*` / `td_w*` multiply activations and stay Δ-PoT)
 _ADDITIVE_HINTS = re.compile(
-    r"(embed|emb_|ln|norm|scale|bias|mu_|time_mix|time_decay|time_first|"
-    r"decay|bonus|gamma|beta|_shift|pos_emb|a_log|dt_bias|conv)",
+    r"(embed|emb_|ln|norm|scale|bias|mu_|time_mix|time_maa|time_faaaa|"
+    r"time_decay|time_first|decay|bonus|gamma|beta|_shift|pos_emb|a_log|"
+    r"dt_bias|conv)",
     re.IGNORECASE,
 )
 

@@ -18,7 +18,12 @@ goes through the same `unpack_leaf`, so mixed-plane trees stay
 bit-identical across the per-op and fused paths.
 
 API:
-  pack_params(params)          -> packed tree (+ additive leaves cast bf16)
+  pack_params(params)          -> packed tree (+ additive leaves cast bf16);
+                                 already-packed leaves pass through
+  pack_layerwise(top, make_layer, n_layers, operands)
+                               -> the same W8 tree, made and packed one
+                                 layer at a time (a model whose f32 tree
+                                 does not fit the device)
   unpack_params(packed)        -> compute tree (call inside jit)
   unpack_leaf(leaf)            -> decode ONE packed leaf (shared by the
                                  fused decode kernels so in-kernel decode is
@@ -42,6 +47,8 @@ Param preparation (one pass, shared by every serving path):
 from __future__ import annotations
 
 import dataclasses
+import logging
+import time
 from typing import Any
 
 import numpy as np
@@ -49,10 +56,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.quant.delta_pot import (
-    FORMAT_W4, FORMAT_W8, dpot_decode_codes, dpot_pack_int8,
-    dpot_pack_nibbles, dpot_quantize)
+    FORMAT_W4, FORMAT_W8, dpot_channel_scale, dpot_decode_codes,
+    dpot_pack_int8, dpot_pack_nibbles, dpot_quantize, dpot_quantize_scaled)
 from repro.core.quant.policy import PlanePolicy, classify_param
 from repro.core.quant.vq import vq_dequantize, vq_quantize
+from repro.runtime.monitor import span
 
 # The three quantized weight-plane leaf forms (one dict shape per plane):
 #   w8 — {"packed":  uint8 (..., K, N),   "scale": f32 (1,...,N)}  sign+7b
@@ -90,12 +98,17 @@ def pack_params(params, policy: PlanePolicy | None = None):
     historical behavior).  With a `PlanePolicy`, each tensor's plane is
     selected per tensor (proxy-guided or forced) — "w4" halves the stored
     code bytes via nibble pairing (falling back to W8 when the contraction
-    axis is odd, so any tree packs), "vq" stores uint8 codebook indices."""
-    flat, tdef = jax.tree_util.tree_flatten_with_path(params)
+    axis is odd, so any tree packs), "vq" stores uint8 codebook indices.
+    A leaf that is already packed passes through unchanged, so a tree that
+    `pack_layerwise` made packs to itself."""
+    flat, tdef = jax.tree_util.tree_flatten_with_path(
+        params, is_leaf=is_packed_leaf)
     out = []
     for path, leaf in flat:
         key = jax.tree_util.keystr(path)
-        if classify_param(key, leaf) == "matmul":
+        if is_packed_leaf(leaf):
+            out.append(leaf)
+        elif classify_param(key, leaf) == "matmul":
             plane = "w8" if policy is None else policy.plane_for(key, leaf)
             if plane == "w4" and (leaf.ndim < 2 or leaf.shape[-2] % 2):
                 plane = "w8"        # nibble pairing needs an even axis -2
@@ -114,6 +127,91 @@ def pack_params(params, policy: PlanePolicy | None = None):
             out.append(leaf.astype(jnp.bfloat16)
                        if hasattr(leaf, "astype") else leaf)
     return jax.tree_util.tree_unflatten(tdef, out)
+
+
+def pack_layerwise(top, make_layer, n_layers: int, operands=(), *,
+                   log=None):
+    """`pack_params({**top, "blocks": stacked layers})` on the W8 plane,
+    made one layer at a time: the stacked f32 tree is never held.
+
+    `make_layer(l, *operands)` gives layer l's f32 tree and may be traced
+    in l; `operands` (a seed's key, say) are arguments of the jitted
+    passes, not constants, so every seed runs the same programs.  The
+    W8 scale is one per output column of the STACKED leaf, so two passes,
+    each one jitted loop over the layers:
+
+      amax  — each matmul leaf's per-column max |w| over every layer
+              (host span `setup.w8_amax`);
+      pack  — each layer made again, coded with that shared scale and
+              written into preallocated stacked code buffers; every other
+              leaf cast to bf16 into its stacked buffer (`setup.w8_pack`).
+
+    The peak is the packed tree plus about one f32 layer.  The result
+    equals the whole-tree `pack_params` bit for bit: the same codes, the
+    scales in their (1, ..., N) shape, the same bf16 leaves.  `log` gets
+    each pass's seconds (default: this module's logger)."""
+    log = log or logging.getLogger(__name__).info
+    shapes = jax.eval_shape(make_layer, jnp.int32(0), *operands)
+    flat, tdef = jax.tree_util.tree_flatten_with_path(shapes)
+    blocks_key = (jax.tree_util.DictKey("blocks"),)
+    # classified as the stacked leaf of the whole tree would be
+    matmul = [classify_param(
+        jax.tree_util.keystr(blocks_key + tuple(path)),
+        jax.ShapeDtypeStruct((n_layers, *leaf.shape), leaf.dtype))
+        == "matmul" for path, leaf in flat]
+    coded = [leaf for (_, leaf), mm in zip(flat, matmul) if mm]
+
+    @jax.jit
+    def amax_pass(operands):
+        def body(l, acc):
+            got = [w for w, mm in zip(
+                jax.tree_util.tree_leaves(make_layer(l, *operands)), matmul)
+                   if mm]
+            return tuple(jnp.maximum(a, jnp.max(
+                jnp.abs(w.astype(jnp.float32)),
+                axis=tuple(range(w.ndim - 1)))) for a, w in zip(acc, got))
+        init = tuple(jnp.zeros(leaf.shape[-1:], jnp.float32)
+                     for leaf in coded)
+        return jax.lax.fori_loop(0, n_layers, body, init)
+
+    @jax.jit
+    def pack_pass(scales, operands):
+        def body(l, bufs):
+            it = iter(scales)
+            out = []
+            layer = jax.tree_util.tree_leaves(make_layer(l, *operands))
+            for buf, w, mm in zip(bufs, layer, matmul):
+                if mm:
+                    v = dpot_pack_int8(dpot_quantize_scaled(
+                        w, next(it)[0], FORMAT_W8))
+                else:
+                    v = w.astype(jnp.bfloat16)
+                out.append(jax.lax.dynamic_update_index_in_dim(
+                    buf, v, l, 0))
+            return tuple(out)
+        bufs = tuple(jnp.zeros((n_layers, *leaf.shape),
+                               jnp.uint8 if mm else jnp.bfloat16)
+                     for (_, leaf), mm in zip(flat, matmul))
+        return jax.lax.fori_loop(0, n_layers, body, bufs)
+
+    t0 = time.perf_counter()
+    with span("setup.w8_amax"):
+        amax = jax.block_until_ready(amax_pass(tuple(operands)))
+    t1 = time.perf_counter()
+    # the scale `dpot_quantize(axis=-1)` takes from the stacked leaf
+    scales = tuple(
+        dpot_channel_scale(a, FORMAT_W8).reshape((1,) * leaf.ndim + a.shape)
+        for a, leaf in zip(amax, coded))
+    with span("setup.w8_pack"):
+        codes = jax.block_until_ready(pack_pass(scales, tuple(operands)))
+    t2 = time.perf_counter()
+    log(f"layer-wise W8 pack of {n_layers} layers: amax pass "
+        f"{t1 - t0:.3f} s, pack pass {t2 - t1:.3f} s")
+    it = iter(scales)
+    blocks = [{"packed": c, "scale": next(it)} if mm else c
+              for c, mm in zip(codes, matmul)]
+    return {**pack_params(top),
+            "blocks": jax.tree_util.tree_unflatten(tdef, blocks)}
 
 
 def _sign(bit):
@@ -207,6 +305,25 @@ def unpack_params(packed):
     """Packed tree -> bf16 compute tree.  Runs inside jit: the uint8 codes
     are what crosses HBM; the exp2 decode fuses into the matmul."""
     return jax.tree_util.tree_map(unpack_leaf, packed, is_leaf=_is_packed)
+
+
+# the name scope every in-trace decode of a packed weight runs under, so a
+# device trace can tell the decode of the weights from the matmuls that
+# use them (the compiled operations carry it in their metadata)
+UNPACK_SCOPE = "w8_unpack"
+
+
+def unpack_layer(tree, dtype):
+    """Decode the packed leaves of `tree` (one layer's weights, or the
+    head) to `dtype` under the `w8_unpack` name scope; plain leaves pass
+    through.  Element for element what `unpack_params` followed by the
+    compute cast gives, so a per-op decode that unpacks each layer inside
+    its layer scan is bit-identical to one that unpacks the whole tree
+    before it — while holding one layer's bf16 weights, not the model's."""
+    with jax.named_scope(UNPACK_SCOPE):
+        return jax.tree_util.tree_map(
+            lambda l: unpack_leaf(l).astype(dtype) if _is_packed(l) else l,
+            tree, is_leaf=_is_packed)
 
 
 # ---------------------------------------------------------------------------

@@ -27,11 +27,30 @@ def wkv6_init_state(batch: int, heads: int, head_dim: int,
     return jnp.zeros((batch, heads, head_dim, head_dim), dtype)
 
 
+def decay_update(w_col, state, kv):
+    """diag(w) S + k⊗v, rounded in an order no compiler can change.
+
+    A compiler may contract a multiply into the add that consumes it (a
+    fused multiply-add skips the product's rounding; XLA:CPU does so in
+    some fusions and not in others), so two programs of the same trace
+    may round differently — which flips a bf16-stored state element now
+    and then.
+    So the decay w (f32) is split into w_hi, w rounded to bf16, and the
+    exact remainder w_lo = w - w_hi (at most 16 significant bits), and
+    the update is (w_hi S + k⊗v) + w_lo S.  With the state stored in
+    bf16 and k, v bf16 values, every product here is exact in f32, so
+    contracted or not, each add rounds once and the bits are the same.
+    `w_col` is w with a trailing axis of 1 (the rows of S)."""
+    w_hi = w_col.astype(jnp.bfloat16).astype(jnp.float32)
+    w_lo = w_col - w_hi
+    return (w_hi * state + kv) + w_lo * state
+
+
 def wkv6_step(state: jnp.ndarray, r, k, v, w, u):
     """One decode step. r,k,v,w: (B,H,N); u: (H,N); state: (B,H,N,N)."""
     kv = k[..., :, None] * v[..., None, :]               # (B,H,N,N)
     y = jnp.einsum("bhn,bhnm->bhm", r, state + u[..., :, None] * kv)
-    new_state = w[..., :, None] * state + kv
+    new_state = decay_update(w[..., :, None], state, kv)
     return new_state, y
 
 

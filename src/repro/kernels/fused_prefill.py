@@ -42,6 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant.serving import is_packed_leaf, leaf_plane, unpack_leaf
 from repro.kernels.common import f32_dot, interpret_default
@@ -110,6 +111,13 @@ def dpot_chunk_matmul(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,
     scale = scale.reshape(1, N)
     bm, (bn, gn) = _fit(bm, M), _fit_lanes(bn, N)
     out_dt = jnp.result_type(x.dtype, jnp.dtype(dt))
+    interpret = interpret_default(interpret)
+    # the x, code, scale and output tiles, each double-buffered by the
+    # pipeline, and room for the compiler's own: a full-K tile outgrows
+    # the default scoped VMEM (16 MiB) from K = 8192 (d_ff 14,336 needs
+    # 28.5 MiB)
+    block_bytes = (bm * K * x.dtype.itemsize + K * bn + 4 * bn
+                   + bm * bn * jnp.dtype(out_dt).itemsize)
     return pl.pallas_call(
         functools.partial(_mm_kernel, dt=jnp.dtype(dt)),
         grid=(M // bm, gn),
@@ -120,7 +128,9 @@ def dpot_chunk_matmul(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dt),
-        interpret=interpret_default(interpret),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            vmem_limit_bytes=2 * block_bytes + (16 << 20)),
+        interpret=interpret,
     )(x, wq, scale)
 
 

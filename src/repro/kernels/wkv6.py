@@ -25,7 +25,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.wkv.wkv6 import decay_update
 from repro.kernels.common import interpret_default
 
 
@@ -84,6 +86,7 @@ def wkv6_pallas(r, k, v, w, u, s0=None, *, chunk: int = 64,
     st_spec = pl.BlockSpec((1, 1, N, N), lambda b, h: (b, h, 0, 0))
     y, sf = pl.pallas_call(
         functools.partial(_kernel, T=T, C=C, N=N),
+        name="wkv6_pallas",
         grid=(B, H),
         in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, u_spec, st_spec],
         out_specs=[seq_spec, st_spec],
@@ -101,7 +104,7 @@ def wkv6_pallas(r, k, v, w, u, s0=None, *, chunk: int = 64,
 # ---------------------------------------------------------------------------
 
 
-def _seq_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, *refs,
+def _seq_kernel(live_ref, r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, *refs,
                 T: int, masked: bool, carry: str | None):
     refs = list(refs)
     valid_ref = refs.pop(0) if masked else None
@@ -117,17 +120,32 @@ def _seq_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, *refs,
         vt = v_ref[sl].astype(jnp.float32)[0]
         wt = w_ref[sl].astype(jnp.float32)[0]
         # exact wkv6_step: y = r @ (S + diag(u) k⊗v); S' = diag(w) S + k⊗v
+        # in `decay_update`'s rounding order
         kv = kt[:, None] * vt[None, :]                         # (N,N)
-        y = jnp.einsum("n,nm->m", rt, S + u[:, None] * kv)
+        # r as a (1, N) row: Mosaic takes a matmul of 2-D operands only
+        y = jnp.dot(rt[None, :], S + u[:, None] * kv,
+                    preferred_element_type=jnp.float32)[0]
         y_ref[sl] = y[None].astype(y_ref.dtype)
-        S_new = wt[:, None] * S + kv
+        S_new = decay_update(wt[:, None], S, kv)
         if masked:
             ok = valid_ref[0, pl.ds(t, 1), :][0, 0] != 0
             S_new = jnp.where(ok, S_new, S)
         return snap(S_new)
 
-    S = jax.lax.fori_loop(0, T, body, s0_ref[0, 0].astype(jnp.float32))
-    sf_ref[0, 0] = S
+    live = live_ref[pl.program_id(0)] != 0
+
+    @pl.when(live)
+    def _walk():
+        sf_ref[0, 0] = jax.lax.fori_loop(
+            0, T, body, s0_ref[0, 0].astype(jnp.float32))
+
+    # a lane with no valid step: every step would keep the state, so it
+    # leaves as it came (snapped, as each masked step snaps it); nothing
+    # reads its y
+    @pl.when(jnp.logical_not(live))
+    def _skip():
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+        sf_ref[0, 0] = snap(s0_ref[0, 0].astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "carry_dtype"))
@@ -139,30 +157,38 @@ def wkv6_seq_pallas(r, k, v, w, u, s0=None, *,
     the whole window, advanced with the exact `wkv6_step` ops so the result
     is BIT-identical to scanning the step (the prefill contract).  `valid`
     (B, T) discards masked steps' state updates; `carry_dtype` rounds the
-    carry through the pool dtype every step, both as in the per-op oracle."""
+    carry through the pool dtype every step, both as in the per-op oracle.
+    A lane with no valid step is not walked (a prefill call that admits
+    one request of 32 walks one lane): its state passes through and its y
+    is 0."""
     B, T, H, N = r.shape
     if s0 is None:
         s0 = jnp.zeros((B, H, N, N), jnp.float32)
+    # per lane, whether any step is valid: scalar-prefetched, so each
+    # index map takes it as a third argument
+    live = (jnp.ones((B,), jnp.int32) if valid is None else
+            jnp.any(valid, axis=1).astype(jnp.int32))
     # head-major layout so each grid cell reads a contiguous (T, N) strip
     tr = lambda x: jnp.transpose(x, (0, 2, 1, 3))         # (B,H,T,N)
-    seq_spec = pl.BlockSpec((1, 1, T, N), lambda b, h: (b, h, 0, 0))
-    u_spec = pl.BlockSpec((1, 1, N), lambda b, h: (h, 0, 0))
-    st_spec = pl.BlockSpec((1, 1, N, N), lambda b, h: (b, h, 0, 0))
+    seq_spec = pl.BlockSpec((1, 1, T, N), lambda b, h, _: (b, h, 0, 0))
+    u_spec = pl.BlockSpec((1, 1, N), lambda b, h, _: (h, 0, 0))
+    st_spec = pl.BlockSpec((1, 1, N, N), lambda b, h, _: (b, h, 0, 0))
     operands = [tr(r), tr(k), tr(v), tr(w), u.reshape(H, 1, N), s0]
     in_specs = [seq_spec, seq_spec, seq_spec, seq_spec, u_spec, st_spec]
     if valid is not None:
         operands.append(valid.astype(jnp.int32).reshape(B, T, 1))
-        in_specs.append(pl.BlockSpec((1, T, 1), lambda b, h: (b, 0, 0)))
+        in_specs.append(pl.BlockSpec((1, T, 1), lambda b, h, _: (b, 0, 0)))
     y, sf = pl.pallas_call(
         functools.partial(_seq_kernel, T=T, masked=valid is not None,
                           carry=carry_dtype),
-        grid=(B, H),
-        in_specs=in_specs,
-        out_specs=[seq_spec, st_spec],
+        name="wkv6_seq_pallas",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, H), in_specs=in_specs,
+            out_specs=[seq_spec, st_spec]),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, T, N), jnp.float32),
             jax.ShapeDtypeStruct((B, H, N, N), jnp.float32),
         ],
         interpret=interpret_default(interpret),
-    )(*operands)
+    )(live, *operands)
     return jnp.transpose(y, (0, 2, 1, 3)), sf

@@ -254,8 +254,14 @@ class Model:
         return self.module.decode_state_axes(self.cfg)
 
     def decode_step(self, params, state, tokens, pos):
-        return self.module.decode_step(self.cast_params(params), state,
-                                       tokens, pos, self.cfg)
+        """The per-op decode step.  The cast is packed-aware
+        (`core.quant.serving.cast_compute`, equal to `cast_params` on a
+        plain tree), so a packed tree reaches the module whole and its
+        leaves decode layer by layer inside the step."""
+        from repro.core.quant.serving import cast_compute
+        return self.module.decode_step(
+            cast_compute(params, jnp.dtype(self.cfg.dtype)), state, tokens,
+            pos, self.cfg)
 
     @property
     def has_fused_decode(self) -> bool:
@@ -326,8 +332,8 @@ class Model:
 
     def prepare_prefill_params(self, params):
         """One-time host-side prep for the fused prefill: pre-decode any
-        packed leaves the chunk datapath consumes element-wise (rwkv6's
-        time_maa / maa_w2 / time_faaaa; rwkv4 needs nothing).  Run OUTSIDE
+        packed leaves the chunk datapath consumes outside a matmul (rwkv6's
+        maa_w2; rwkv4 needs nothing).  Run OUTSIDE
         the step, like `prepare_fused_model_params`."""
         desc = self.prefill_paths().get("chunked")
         return params if desc is None else \

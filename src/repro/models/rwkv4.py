@@ -398,20 +398,28 @@ def prefill_chunk(params, state, tokens, valid, pos, cfg: ModelConfig, *,
 
 def decode_step(params, state, tokens, pos, cfg: ModelConfig, *,
                 hw: bool = False):
-    """tokens: (B,1). Returns (logits (B,1,V), new_state)."""
+    """tokens: (B,1). Returns (logits (B,1,V), new_state).
+
+    Takes a plain or a packed tree: packed Δ-PoT leaves decode one layer at
+    a time inside the layer scan and the head once per call
+    (`core.quant.serving.unpack_layer`), bit-identical to decoding the
+    whole tree first."""
     del pos  # RWKV state is position-free
+    from repro.core.quant.serving import (broadcast_packed_scales,
+                                          unpack_layer)
     nm = _numerics(hw)
-    x = jnp.take(params["embed"], tokens[:, 0], axis=0).astype(
-        jnp.dtype(cfg.dtype))                              # (B,D)
+    dt = jnp.dtype(cfg.dtype)
+    x = jnp.take(params["embed"], tokens[:, 0], axis=0).astype(dt)  # (B,D)
     x = L.apply_norm(params["ln0"], x[:, None], "layernorm")[:, 0]
+    blocks = broadcast_packed_scales(params["blocks"], cfg.n_layers)
 
     def body(x, xs):
         lp, st = xs
-        return block_decode(lp, st, x, cfg, nm)
+        return block_decode(unpack_layer(lp, dt), st, x, cfg, nm)
 
-    x, new_state = jax.lax.scan(body, x, (params["blocks"], state))
+    x, new_state = jax.lax.scan(body, x, (blocks, state))
     x = L.apply_norm(params["ln_f"], x[:, None], "layernorm")
-    logits = x @ params["head"].astype(x.dtype)
+    logits = x @ unpack_layer(params["head"], x.dtype).astype(x.dtype)
     return logits, new_state
 
 

@@ -25,9 +25,6 @@ from repro.models import layers as L
 from repro.models.param import P
 from repro.parallel.sharding import constrain
 
-_MAA_RANK = 32   # low-rank dims of the data-dependent mixes (HF config: 32)
-_TD_RANK = 64    # low-rank dim of the data-dependent decay
-
 
 def _stack(spec, n: int):
     return jax.tree_util.tree_map(
@@ -40,6 +37,7 @@ def _block_spec(cfg: ModelConfig) -> dict:
     d, f = cfg.d_model, cfg.d_ff
     H = cfg.n_heads
     N = cfg.rwkv_head_dim
+    rm, rd = cfg.rwkv_mix_rank, cfg.rwkv_decay_rank
     assert H * N == d, f"heads {H} x head_dim {N} != d_model {d}"
     return {
         "ln1": L.spec_norm(d, "layernorm"),
@@ -48,11 +46,11 @@ def _block_spec(cfg: ModelConfig) -> dict:
             "time_maa_x": P((d,), (None,), init="uniform", scale=0.5),
             # per-stream mus: w, k, v, r, g
             "time_maa": P((5, d), (None, None), init="uniform", scale=0.5),
-            "maa_w1": P((d, 5 * _MAA_RANK), (None, None), scale=0.01),
-            "maa_w2": P((5, _MAA_RANK, d), (None, None, None), scale=0.01),
+            "maa_w1": P((d, 5 * rm), (None, None), scale=0.01),
+            "maa_w2": P((5, rm, d), (None, None, None), scale=0.01),
             "time_decay": P((d,), (None,), init="zeros"),
-            "td_w1": P((d, _TD_RANK), (None, None), scale=0.01),
-            "td_w2": P((_TD_RANK, d), (None, None), scale=0.01),
+            "td_w1": P((d, rd), (None, None), scale=0.01),
+            "td_w2": P((rd, d), (None, None), scale=0.01),
             "time_faaaa": P((H, N), (None, None), init="zeros"),  # bonus u
             "wr": P((d, d), ("fsdp", "tp")),
             "wk": P((d, d), ("fsdp", "tp")),
@@ -93,7 +91,7 @@ def _ddlerp(p, x, dx):
     xxx = x + dx * p["time_maa_x"]
     lead = xxx.shape[:-1]
     dmix = jnp.tanh(xxx @ p["maa_w1"])                 # (..., 5R)
-    dmix = dmix.reshape(*lead, 5, _MAA_RANK)
+    dmix = dmix.reshape(*lead, 5, -1)
     deltas = jnp.einsum("...sr,srd->...sd", dmix, p["maa_w2"])  # (...,5,D)
     mus = p["time_maa"] + deltas                       # (...,5,D)
     return tuple(x + dx * mus[..., i, :] for i in range(5))
@@ -254,9 +252,9 @@ def block_prefill(lp, st, x, valid, cfg: ModelConfig, *,
     Bit-identical to scanning `block_decode` over the window with the
     engine's per-step state masking, for any per-slot PREFIX validity mask
     (the scheduler only emits prefix masks).  Factored the same way
-    `block_decode` was; `lp` must carry time_maa / maa_w2 / time_faaaa as
-    PLAIN leaves (they are consumed element-wise, not by a matmul —
-    `prepare_prefill_params` pre-decodes them once at startup)."""
+    `block_decode` was; `lp` must carry maa_w2 as a PLAIN leaf (it is
+    einsum'd, not fed to a chunk matmul — `prepare_prefill_params`
+    pre-decodes it once at startup)."""
     from repro.kernels.fused_prefill import (
         chunk_matmul, last_valid_select, shifted_prev)
     from repro.kernels.wkv6 import wkv6_seq_pallas
@@ -273,7 +271,7 @@ def block_prefill(lp, st, x, valid, cfg: ModelConfig, *,
     dx = prev.astype(h.dtype) - h
     # ddlerp with the low-rank matmuls chunk-shaped
     xxx = h + dx * p["time_maa_x"]
-    dmix = jnp.tanh(mm(xxx, p["maa_w1"])).reshape(B, C, 5, _MAA_RANK)
+    dmix = jnp.tanh(mm(xxx, p["maa_w1"])).reshape(B, C, 5, -1)
     deltas = jnp.einsum("...sr,srd->...sd", dmix, p["maa_w2"])
     mus = p["time_maa"] + deltas
     xw, xk, xv, xr, xg = (h + dx * mus[..., i, :] for i in range(5))
@@ -345,11 +343,10 @@ def prefill_chunk(params, state, tokens, valid, pos, cfg: ModelConfig, *,
                                 jnp.zeros_like(logits))
 
 
-# packed leaves block_prefill consumes OUTSIDE a matmul: element-wise mixes,
-# the einsum'd low-rank delta table, and the WKV bonus
-PREFILL_PLAIN_LEAVES = tuple(
-    ("blocks", "att", k)
-    for k in ("time_maa_x", "time_maa", "maa_w2", "time_faaaa"))
+# packed leaves block_prefill consumes OUTSIDE a matmul: the einsum'd
+# low-rank delta table (the element-wise mixes and the WKV bonus are
+# additive leaves, stored plain in the compute dtype)
+PREFILL_PLAIN_LEAVES = (("blocks", "att", "maa_w2"),)
 
 
 def prepare_prefill_params(params, cfg: ModelConfig):
@@ -366,19 +363,24 @@ def prepare_prefill_params(params, cfg: ModelConfig):
 
 
 def decode_step(params, state, tokens, pos, cfg: ModelConfig):
-    """tokens: (B,1) -> (logits (B,1,V), new_state)."""
+    """tokens: (B,1) -> (logits (B,1,V), new_state).  Takes a plain or a
+    packed tree: packed Δ-PoT leaves decode one layer at a time inside the
+    layer scan and the head once per call, as in models/rwkv4.py."""
     del pos
-    x = jnp.take(params["embed"], tokens[:, 0], axis=0).astype(
-        jnp.dtype(cfg.dtype))
+    from repro.core.quant.serving import (broadcast_packed_scales,
+                                          unpack_layer)
+    dt = jnp.dtype(cfg.dtype)
+    x = jnp.take(params["embed"], tokens[:, 0], axis=0).astype(dt)
     x = L.apply_norm(params["ln0"], x[:, None], "layernorm")[:, 0]
+    blocks = broadcast_packed_scales(params["blocks"], cfg.n_layers)
 
     def body(x, xs):
         lp, st = xs
-        return block_decode(lp, st, x, cfg)
+        return block_decode(unpack_layer(lp, dt), st, x, cfg)
 
-    x, new_state = jax.lax.scan(body, x, (params["blocks"], state))
+    x, new_state = jax.lax.scan(body, x, (blocks, state))
     x = L.apply_norm(params["ln_f"], x[:, None], "layernorm")
-    logits = x @ params["head"].astype(x.dtype)
+    logits = x @ unpack_layer(params["head"], x.dtype).astype(x.dtype)
     return logits, new_state
 
 

@@ -81,16 +81,19 @@ def masked_state_commit(new_state, old_state, mask, axes):
 
 
 def maybe_unpack(params, quantized: bool):
-    """In-trace Δ-PoT decode for the per-op paths: packed trees unpack
-    INSIDE the jit (uint8 codes cross HBM; the exp2 decode fuses into the
-    consumer matmuls).  Fused paths never call this — their descriptors
-    carry `fused=True` and the kernels decode per leaf.  The unpack runs
-    under the name scope `w8_unpack`, which the compiled program's
-    operations carry in their metadata, so a device trace can tell the
-    decode of the weights from the matmuls that use them."""
+    """In-trace Δ-PoT decode of the whole tree, for the per-op prefill,
+    draft and verify programs: packed trees unpack INSIDE the jit (uint8
+    codes cross HBM; the exp2 decode fuses into the consumer matmuls).
+    The per-op DECODE program does not call this: its step decodes each
+    layer inside the layer scan (`core.quant.serving.unpack_layer`), so it
+    never holds the whole bf16 tree.  Fused paths never call this either —
+    their descriptors carry `fused=True` and the kernels decode per leaf.
+    The unpack runs under the name scope `w8_unpack`, which the compiled
+    program's operations carry in their metadata, so a device trace can
+    tell the decode of the weights from the matmuls that use them."""
     if quantized:
-        from repro.core.quant.serving import unpack_params
-        with jax.named_scope("w8_unpack"):
+        from repro.core.quant.serving import UNPACK_SCOPE, unpack_params
+        with jax.named_scope(UNPACK_SCOPE):
             return unpack_params(params)
     return params
 
@@ -376,7 +379,7 @@ class ExecutionPlan:
         """The selected decode path (or an explicit `name` override — the
         fallback twins use "per_op") as a uniform
         (params, state, tokens) -> (logits, new_state) step."""
-        model, quantized = self.model, self.prepared.quantized
+        model = self.model
         if name is None:
             name = self.decode_desc.name
         if name == "model":
@@ -388,8 +391,8 @@ class ExecutionPlan:
             # single-launch block kernel; packed leaves decode per launch
             return lambda p, s, t: model.decode_step_fused(
                 p, s, t, jnp.int32(0))
-        return lambda p, s, t: model.decode_step(
-            maybe_unpack(p, quantized), s, t, jnp.int32(0))
+        # per-op: a packed tree decodes layer by layer inside the step
+        return lambda p, s, t: model.decode_step(p, s, t, jnp.int32(0))
 
     def _build_decode(self, *, path: Optional[str] = None,
                       path_params=None, count_key: str = "decode"):

@@ -187,6 +187,37 @@ class TestWkv6Kernel:
         np.testing.assert_allclose(np.asarray(s), np.asarray(sr),
                                    rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("carry", [None, "bfloat16"])
+    def test_sequence_kernel_skips_lanes_with_no_valid_step(self, rng,
+                                                            carry):
+        """Lanes with no valid step are not walked: their state leaves as
+        it came (snapped to the carry dtype) and their y is 0; every other
+        lane is bit for bit what the kernel gives it alone."""
+        from repro.kernels.wkv6 import wkv6_seq_pallas
+        B, T, H, N = 4, 8, 2, 16
+        f32 = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+        r, k, v = f32(B, T, H, N), f32(B, T, H, N), f32(B, T, H, N)
+        w = jnp.asarray(rng.uniform(0.2, 0.99, (B, T, H, N)), jnp.float32)
+        u, s0 = f32(H, N), f32(B, H, N, N)
+        valid = jnp.asarray(np.arange(T)[None] < np.array([[T], [0], [3],
+                                                           [0]]))
+        y, s = wkv6_seq_pallas(r, k, v, w, u, s0, valid=valid,
+                               carry_dtype=carry)
+        snap = s0 if carry is None else s0.astype(carry).astype(jnp.float32)
+        for b in (1, 3):
+            np.testing.assert_array_equal(np.asarray(y[b]), 0.0)
+            np.testing.assert_array_equal(np.asarray(s[b]),
+                                          np.asarray(snap[b]))
+        for b in (0, 2):
+            one = lambda a: a[b:b + 1]
+            yb, sb = wkv6_seq_pallas(one(r), one(k), one(v), one(w), u,
+                                     one(s0), valid=one(valid),
+                                     carry_dtype=carry)
+            np.testing.assert_array_equal(np.asarray(y[b]),
+                                          np.asarray(yb[0]))
+            np.testing.assert_array_equal(np.asarray(s[b]),
+                                          np.asarray(sb[0]))
+
 
 class TestExpSigKernels:
     @pytest.mark.parametrize("n", [100, 4096, 5000])

@@ -173,3 +173,16 @@ def test_shape_skips_documented():
             assert sup == "ok"
         else:
             assert sup.startswith("skip")
+
+
+def test_rwkv6_7b_lora_ranks_are_published():
+    """RWKV-6 7B's ddlerp LoRA has rank 64 for each of its five mixes and
+    its decay LoRA rank 128 (d_model 4096); the smoke preset keeps the
+    smaller defaults, 32 and 64."""
+    att = get_model("rwkv6-7b").abstract_params()["blocks"]["att"]
+    assert att["maa_w1"].shape == (32, 4096, 5 * 64)
+    assert att["maa_w2"].shape == (32, 5, 64, 4096)
+    assert att["td_w1"].shape == (32, 4096, 128)
+    assert att["td_w2"].shape == (32, 128, 4096)
+    smoke = get_model("rwkv6-7b", smoke=True).cfg
+    assert (smoke.rwkv_mix_rank, smoke.rwkv_decay_rank) == (32, 64)

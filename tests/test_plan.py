@@ -91,9 +91,48 @@ class TestPreparedParams:
                           fused_prefill=True)
         raw_att = plan.prepared.raw["blocks"]["att"]
         pre_att = plan.prepared.prefill["blocks"]["att"]
-        assert is_packed_leaf(raw_att["time_maa"])
-        assert not is_packed_leaf(pre_att["time_maa"])   # pre-decoded
+        # the paper's additive plane: token-shift mu and bonus u are
+        # stored plain in bf16, never W8-coded
+        for name in ("time_maa_x", "time_maa", "time_faaaa"):
+            assert not is_packed_leaf(raw_att[name])
+            assert raw_att[name].dtype == jnp.bfloat16
+        assert is_packed_leaf(raw_att["maa_w2"])
+        assert not is_packed_leaf(pre_att["maa_w2"])     # pre-decoded
         assert is_packed_leaf(pre_att["wk"])             # still packed
+
+    @pytest.mark.parametrize("arch", ["rwkv4-169m", "rwkv6-7b"])
+    def test_per_op_decode_unpacks_layer_by_layer(self, arch, rng):
+        """The per-op decode program decodes each layer's packed leaves
+        inside the layer scan: its top level never holds a stacked bf16
+        weight, and its logits and state are bit-identical to decoding
+        the whole tree before the step."""
+        from repro.core.quant.serving import unpack_params
+        from repro.kernels.common import exact_jit
+        model = get_model(arch, smoke=True)
+        cfg = model.cfg
+        plan = build_plan(model, model.init_params(jax.random.PRNGKey(1)),
+                          quantized=True)
+        packed = plan.prepared.decode
+        B = 3
+        state = jax.tree_util.tree_map(
+            lambda x: jnp.asarray(rng.normal(size=x.shape), x.dtype),
+            model.init_slot_state(B, 0, jnp.bfloat16))
+        toks = jnp.asarray(rng.integers(0, cfg.vocab, (B, 1)), jnp.int32)
+        want = exact_jit(lambda p, s, t: model.decode_step(
+            unpack_params(p), s, t, jnp.int32(0)))(packed, state, toks)
+        top = jax.make_jaxpr(plan._decode_step())(packed, state, toks).jaxpr
+        stacked = [v.aval.shape for e in top.eqns for v in e.outvars
+                   if v.aval.dtype == jnp.bfloat16 and v.aval.ndim == 3
+                   and v.aval.shape[0] == cfg.n_layers
+                   and v.aval.shape[1] >= cfg.d_model]
+        assert stacked == []
+        logits, new = plan.decode_fn(B)(state, toks, np.ones((B,), bool))
+        np.testing.assert_array_equal(np.asarray(logits, np.float32),
+                                      np.asarray(want[0], np.float32))
+        for a, b in zip(jax.tree_util.tree_leaves(new),
+                        jax.tree_util.tree_leaves(want[1])):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
 
     def test_megakernel_prep_builds_layer_stack(self, rwkv4):
         from repro.core.quant.serving import FusedLayerStack

@@ -12,7 +12,7 @@ except ImportError:   # optional dep: property tests importorskip at run time
     given, settings, st = hypothesis_stubs()
 
 from repro.core.quant.delta_pot import (
-    DPotFormat, FORMAT_W9, FORMAT_W8, FORMAT_POT4, dpot_levels,
+    DPotFormat, FORMAT_W9, FORMAT_W8, FORMAT_W4, FORMAT_POT4, dpot_levels,
     dpot_max_level, dpot_quantize, dpot_dequantize, dpot_fake_quant,
     dpot_pack_int8, dpot_unpack_int8, dpot_decode_codes)
 from repro.core.quant.uniform import (
@@ -22,6 +22,7 @@ from repro.core.quant.schemes import (
 from repro.core.quant.policy import (
     QuantPolicy, classify_param, fake_quantize_tree, quantize_tree,
     dequantize_tree)
+from repro.models.registry import get_model
 
 
 # ---------------------------------------------------------------------------
@@ -143,6 +144,28 @@ class TestDPotQuantize:
         with pytest.raises(ValueError):
             dpot_pack_int8(q)
 
+    @pytest.mark.parametrize("fmt", [FORMAT_W8, FORMAT_W9, FORMAT_W4,
+                                     FORMAT_POT4, DPotFormat((2, 2))],
+                             ids=lambda f: str(f.ks))
+    def test_closed_form_code_equals_search(self, fmt):
+        """The closed-form nearest code is the midpoint search's code at
+        every level and every midpoint, one ulp either side of each, and
+        off the grid in and out of range (ties go to the lower level)."""
+        from repro.core.quant.delta_pot import (
+            _closed_form_code, _searched_code, _sorted_levels_np)
+        levels, _, mids = _sorted_levels_np(fmt.ks)
+        pts = np.concatenate([mids, levels]).astype(np.float32)
+        r = np.random.default_rng(0)
+        y = np.concatenate([
+            pts, np.nextafter(pts, np.float32(0)),
+            np.nextafter(pts, np.float32(2)),
+            r.uniform(0, 1, 20_000).astype(np.float32),
+            np.exp(r.uniform(np.log(1e-12), 0.5, 20_000)).astype(np.float32),
+            np.array([0.0, 1e-45, 1.0, 2.0, 1e30, np.inf], np.float32)])
+        got = np.asarray(_closed_form_code(jnp.asarray(y), fmt.ks))
+        want = np.asarray(_searched_code(jnp.asarray(y), fmt))
+        np.testing.assert_array_equal(got, want)
+
     def test_bytes_accounting(self):
         q = dpot_quantize(jnp.ones((128, 128)), FORMAT_W8, axis=1)
         nb = q.nbytes_hardware()
@@ -224,6 +247,20 @@ class TestPolicy:
         out = fake_quantize_tree(params)
         assert jax.tree_util.tree_structure(out) == \
             jax.tree_util.tree_structure(params)
+
+    @pytest.mark.parametrize("name,plane", [
+        ("time_maa_x", "additive"), ("time_maa", "additive"),
+        ("time_faaaa", "additive"), ("maa_w1", "matmul"),
+        ("maa_w2", "matmul"), ("td_w1", "matmul"), ("td_w2", "matmul")])
+    def test_rwkv6_mu_and_bonus_are_additive(self, name, plane):
+        """The paper's rule on RWKV-6's stacked leaves: token-shift mu and
+        the bonus u are used additively whatever their rank; the LoRA
+        matrices multiply activations."""
+        model = get_model("rwkv6-7b", smoke=True)
+        att = model.abstract_params()["blocks"]["att"]
+        assert att[name].ndim >= 2
+        assert classify_param(f"['blocks']['att']['{name}']",
+                              att[name]) == plane
 
 
 # ---------------------------------------------------------------------------

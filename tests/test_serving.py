@@ -7,13 +7,23 @@ import pytest
 
 from repro.configs.base import ShapeConfig
 from repro.core.quant.serving import (
-    pack_params, packed_abstract, replicate_fsdp, serving_axes,
-    unpack_params)
+    pack_layerwise, pack_params, packed_abstract, replicate_fsdp,
+    serving_axes, unpack_params)
 from repro.core.quant.delta_pot import (
     FORMAT_W8, dpot_quantize, dpot_dequantize)
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_serve_step
 from repro.models.registry import get_model
+
+
+def _assert_same(a, b):
+    """Two trees with the same structure, shapes, dtypes and bits."""
+    assert jax.tree_util.tree_structure(a) == jax.tree_util.tree_structure(b)
+    for x, y in zip(jax.tree_util.tree_leaves(a),
+                    jax.tree_util.tree_leaves(b)):
+        assert x.shape == y.shape and x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x).view(np.uint8),
+                                      np.asarray(y).view(np.uint8))
 
 
 class TestPackUnpack:
@@ -51,6 +61,33 @@ class TestPackUnpack:
         flat_a = jax.tree_util.tree_leaves(ab_shapes)
         # scale shapes differ in broadcast form only; compare packed dtypes
         assert flat_r == flat_a
+
+    def test_packed_leaves_pass_through(self, rng):
+        w = jnp.asarray(rng.normal(size=(16, 8)), jnp.float32)
+        packed = pack_params({"blocks": {"wk": w},
+                              "ln0": {"scale": jnp.ones((8,))}})
+        again = pack_params(packed)
+        assert again["blocks"]["wk"]["packed"] is \
+            packed["blocks"]["wk"]["packed"]
+        assert again["blocks"]["wk"]["scale"] is \
+            packed["blocks"]["wk"]["scale"]
+        _assert_same(again, packed)
+
+    @pytest.mark.parametrize("arch", ["rwkv4-169m", "rwkv6-7b"])
+    def test_layerwise_pack_equals_whole_tree(self, arch):
+        """Made one layer at a time (the layer a traced index into the
+        stacked tree), the pack equals `pack_params` of the whole tree
+        bit for bit: codes, (1, ..., N) scales and bf16 leaves."""
+        model = get_model(arch, smoke=True)
+        params = model.init_params(jax.random.PRNGKey(3))
+        top = {k: v for k, v in params.items() if k != "blocks"}
+        make_layer = lambda l, blocks: jax.tree_util.tree_map(
+            lambda x: jax.lax.dynamic_index_in_dim(x, l, 0, False), blocks)
+        seconds = []
+        got = pack_layerwise(top, make_layer, model.cfg.n_layers,
+                             (params["blocks"],), log=seconds.append)
+        _assert_same(got, pack_params(params))
+        assert "amax pass" in seconds[0] and "pack pass" in seconds[0]
 
     def test_replicate_fsdp(self):
         axes = {"w": ("fsdp", "tp"), "b": (None,)}

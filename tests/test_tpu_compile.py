@@ -4,8 +4,9 @@ Interpret mode runs a kernel's math on the CPU but checks none of the
 chip's rules (tile alignment, 32-bit matmul accumulators, scoped VMEM),
 so these tests ask the TPU compiler itself, for a described `v5e:2x2`
 chip with nothing attached, at RWKV-4 169M widths (d_model 768, d_ff 3072,
-vocab 50,277) and a batch of 8.  Nothing runs: a pass says the kernels
-compile, not what they compute or how fast.
+vocab 50,277) and a batch of 8, and at the benchmark's larger shapes
+(RWKV-4 1.5B, RWKV-6 7B).  Nothing runs: a pass says the kernels compile,
+not what they compute or how fast.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and a test run's workers
@@ -81,6 +82,32 @@ def test_w8_chunk_matmul(one_chip, m, k, n):
                                                interpret=False),
              sds((m, k), jnp.bfloat16), sds((k, n), jnp.uint8),
              sds((1, n), jnp.float32))
+
+
+@pytest.mark.parametrize("m,k,n", [(256, 8192, 2048), (256, 4096, 14336),
+                                   (256, 14336, 4096), (256, 4096, 320)])
+def test_w8_chunk_matmul_wide(one_chip, m, k, n):
+    """The same kernel at 1.5B's and 7B's widths, with a 256-row tile as
+    in a 32 x 64 chunk: full-K tiles from K = 8192 need more than the
+    default scoped VMEM, which the kernel asks for; RWKV-6's 320-wide
+    ddlerp LoRA is one ragged-free block."""
+    test_w8_chunk_matmul(one_chip, m, k, n)
+
+
+def test_wkv6_sequence_kernel(one_chip):
+    """The masked WKV-6 scan of the chunked prefill, bf16-snapped carry,
+    at RWKV-6 7B's shape in the benchmark: 32 slots x 64-token chunks,
+    64 heads of 64."""
+    from repro.kernels.wkv6 import wkv6_seq_pallas
+    b, t, h, n = 32, 64, 64, 64
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((b, t), jnp.bool_, sharding=one_chip)
+    _compile(lambda r, k, v, w, u, s, ok: wkv6_seq_pallas(
+        r, k, v, w, u, s, valid=ok, carry_dtype="bfloat16",
+        interpret=False),
+        f32(b, t, h, n), f32(b, t, h, n), f32(b, t, h, n), f32(b, t, h, n),
+        f32(h, n), f32(b, h, n, n), valid)
 
 
 @pytest.mark.parametrize("quantized", [True, False], ids=["w8", "bf16"])
